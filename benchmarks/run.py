@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -39,6 +40,7 @@ def main() -> None:
                             penalty_study, roofline_report, sharded_cohort,
                             sweep_engine)
     from benchmarks.common import BenchSettings, emit
+    from repro.compile_cache import enable_compile_cache
 
     settings = BenchSettings(full=args.full, seeds=args.seeds)
     benches = {
@@ -56,9 +58,11 @@ def main() -> None:
         "sweep_engine": lambda: sweep_engine.main(settings),
     }
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     t0 = time.perf_counter()
+    failed = []
     for key, fn in benches.items():
         if only and key not in only:
             continue
@@ -66,10 +70,15 @@ def main() -> None:
         try:
             fn()
             emit(f"section/{key}", (time.perf_counter() - t) * 1e6, "ok")
-        except Exception as e:  # keep the suite running
+        except Exception as e:  # keep the suite running, fail at the end
+            traceback.print_exc()
+            failed.append(key)
             emit(f"section/{key}", (time.perf_counter() - t) * 1e6,
                  f"ERROR:{type(e).__name__}:{str(e)[:120]}")
     emit("total", (time.perf_counter() - t0) * 1e6, "")
+    if failed:
+        sys.exit(f"benchmarks: {len(failed)} section(s) failed: "
+                 + ", ".join(failed))
 
 
 if __name__ == "__main__":

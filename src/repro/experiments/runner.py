@@ -296,7 +296,6 @@ def _sharded_multi_fn(model, optimizer, prox_mu: float, mesh, n_seg: int,
     key = (id(model), id(optimizer), prox_mu, id(mesh), n_seg, compressed)
     if key in _sharded_multi_cache:
         return _sharded_multi_cache[key]
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     one_client = make_client_step(model, optimizer, prox_mu)
@@ -328,10 +327,14 @@ def _sharded_multi_fn(model, optimizer, prox_mu: float, mesh, n_seg: int,
                     clients_spec(1, 0, axis),
                     P(),                                  # qref replicated
                     clients_spec(1, 0, axis))
-        return shard_map(shard_body, mesh=mesh, in_specs=in_specs,
-                         out_specs=(P(), clients_spec(1, 0, axis)))(
-                             global_b, xs, ys, masks, active, weights,
-                             seg, qref, enabled)
+        # check_vma off: cohort_scan's carry starts device-invariant (the
+        # zero losses, replicated globals) and turns per-device after one
+        # step, which the varying-axes type check rejects as a carry change
+        return jax.shard_map(shard_body, mesh=mesh, in_specs=in_specs,
+                             out_specs=(P(), clients_spec(1, 0, axis)),
+                             check_vma=False)(
+                                 global_b, xs, ys, masks, active, weights,
+                                 seg, qref, enabled)
 
     _sharded_multi_cache[key] = run
     return run

@@ -1,8 +1,12 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On a TPU backend the Pallas kernels run natively; elsewhere (this CPU
-container, and any host without Mosaic) they execute in interpret mode for
-tests or fall back to the pure-jnp reference paths used by the model zoo.
+On a TPU backend (``on_tpu()``) the Pallas kernels always run natively.
+On any other backend — CPU hosts, and the test suite under
+``JAX_PLATFORMS=cpu`` — they run in interpret mode when a caller forces
+them (``force_pallas=True``), and otherwise take the pure-jnp reference
+paths of ``kernels/ref.py``.  The reference branch is therefore never
+taken on the chip: ``chip_smoke.py`` refuses to run unless JAX reports a
+TPU, and on a TPU ``on_tpu()`` is true for every call.
 """
 
 from __future__ import annotations

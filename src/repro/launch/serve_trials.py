@@ -125,6 +125,8 @@ def main():
                          "K macro-steps — the chaos-smoke crash injector")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.experiments import ResultStore
     from repro.experiments.scheduler import TrialQueue, TrialScheduler

@@ -143,6 +143,8 @@ def main():
                          "annotations per span so device profiles line up")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.experiments import (ResultStore, SweepSpec, TrialSpec,
                                    paper_table, parse_preferences, run_sweep)

@@ -76,6 +76,8 @@ def main():
                     help="with --trace: also open jax.profiler trace "
                          "annotations per span")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.mode == "mesh":
         from examples import distributed_fl  # same path, shared driver

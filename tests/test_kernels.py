@@ -8,13 +8,15 @@ import pytest
 from repro.kernels import ref
 from repro.kernels import ops as kernel_ops
 from repro.kernels.fed_aggregate import fed_aggregate
+from repro.kernels.fed_reduce import BLOCK_M
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
 
 KEY = jax.random.PRNGKey(0)
 
 
-@pytest.mark.parametrize("m,n", [(1, 256), (4, 1000), (16, 8192), (50, 4097)])
+@pytest.mark.parametrize("m,n", [(1, 256), (4, 1000), (16, 8192), (50, 4097),
+                                 (BLOCK_M + 44, 1000)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fed_aggregate_sweep(m, n, dtype):
     ks = jax.random.split(KEY, 3)
@@ -120,12 +122,16 @@ def _reduce_case(m, n, t, seed, *, interleave=False, zero_w=0):
 
 
 @pytest.mark.parametrize("m,n,t", [(1, 256, 1), (7, 300, 3), (16, 1024, 4),
-                                   (33, 4097, 8)])
+                                   (33, 4097, 8), (BLOCK_M + 44, 300, 3),
+                                   (2 * BLOCK_M, 300, 3)])
 @pytest.mark.parametrize("mode", ["plain", "normalize", "base", "quant"])
 def test_fed_reduce_pallas_matches_ref_bitwise(m, n, t, mode):
     """Interpret-mode Pallas == jitted reference, bit for bit, in every
     fusion mode — including non-pow2 row counts and column tails (the
-    kernel pads N to its block and M/T to pow2 internally)."""
+    kernel pads N to its column block and M to its row block).  Past
+    BLOCK_M rows the fold walks several row blocks, segments straddle a
+    block edge, and the filler rows of a part-filled last block select no
+    lane."""
     w, rows, seg, base = _reduce_case(m, n, t, seed=m * 1000 + n)
     kw = {}
     if mode == "normalize":
