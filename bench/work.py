@@ -1,0 +1,54 @@
+"""The work a cell asks of the chip, counted from the configuration's widths.
+
+These are the benchmark's own counts: a change to the program cannot change
+how much work a step is said to hold.
+
+- ``train_flops_per_example``: forward and backward of one example through
+  the MLP, 6 FLOPs per weight (2 forward, 4 backward), biases left out.
+- ``fed_reduce_traffic``: what the fused FedAvg reduction has to move and
+  compute for one call over (M, N) rows into T lanes, whatever implements
+  it. Rows are read once; the (T, N) base is read and the (T, N) result
+  written; with the int8 round trip the (T, N) quantization reference is
+  read too. One multiply and one add per row element, plus six elementwise
+  operations per element for the round trip.
+"""
+
+from __future__ import annotations
+
+F32 = 4
+
+
+def mlp_dims(model: dict) -> list:
+    return [model["in_dim"], *model["hidden"], model["n_classes"]]
+
+
+def weight_count(model: dict) -> int:
+    """Weights of the MLP's matrices, biases left out."""
+    d = mlp_dims(model)
+    return sum(a * b for a, b in zip(d, d[1:]))
+
+
+def param_count(model: dict) -> int:
+    """Every parameter the server reduces: weights and biases."""
+    d = mlp_dims(model)
+    return sum(a * b + b for a, b in zip(d, d[1:]))
+
+
+def train_flops_per_example(model: dict) -> float:
+    return 6.0 * weight_count(model)
+
+
+def fed_reduce_traffic(m: int, n: int, t: int, *, quant: bool = False):
+    """(bytes, flops) of one fused reduction call."""
+    nbytes = m * n * F32 + 2 * t * n * F32
+    if quant:
+        nbytes += t * n * F32
+    flops = 2.0 * m * n + (6.0 * m * n if quant else 0.0)
+    return float(nbytes), flops
+
+
+def roofline_seconds(nbytes: float, flops: float, peak: dict):
+    """Least time on the chip and the bound that sets it."""
+    t_mem = nbytes / peak["hbm_bytes_per_s"]
+    t_flop = flops / peak["peak_flops_bf16"]
+    return (t_mem, "bytes") if t_mem >= t_flop else (t_flop, "flops")
