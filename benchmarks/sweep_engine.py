@@ -16,8 +16,10 @@ wall-clock, phase split, speedup, and parity:
                arrival-lane per trial (``--mode async|buffered``)
 
 Wall-clock is split into ``train_s`` (cohort/client training dispatches),
-``eval_s`` (accuracy dispatches), and ``other_s`` (host orchestration)
-through the ``repro.perf`` counters, so the eval-amortization win of the
+``eval_s`` (accuracy dispatches), and ``other_s`` (host orchestration):
+every timed run executes with ``repro.obs`` on (parity-neutral), and
+``train_s``/``eval_s`` are the self time of its ``TRAIN`` and
+``eval``/``eval_stacked`` spans, so the eval-amortization win of the
 stacked evaluator is visible separately from the training win.
 ``--compression int8`` runs the same grid with upload-compressed trials —
 they vectorize lane-wise, so ``sequential_trials`` must stay 0.
@@ -38,11 +40,11 @@ writes it to a file for CI artifact upload):
          "occupancy": ..., "padding_waste": ..., "phase_calls": {...},
          "sequential_trials": 0, ...}
 
-The timed vectorized run executes under the observability subsystem
-(repro.obs, parity-neutral): ``occupancy`` is the mean fraction of the T
-lanes still live per macro-step, ``padding_waste`` the fraction of packed
-cohort steps spent on pow2 padding, and ``phase_calls`` the number of
-train/eval dispatches behind the phase seconds (the amortization factor).
+``occupancy`` is the mean fraction of the T lanes still live per
+macro-step of the timed vectorized run, ``padding_waste`` the fraction of
+packed cohort steps spent on pow2 padding, and ``phase_calls`` the number
+of ``TRAIN``/``eval*`` spans behind the phase seconds (the amortization
+factor).
 
 Usage: PYTHONPATH=src:. python benchmarks/sweep_engine.py [--t 8]
        [--rounds 4] [--mode async] [--compression int8]
@@ -56,7 +58,7 @@ import json
 import time
 
 from benchmarks.common import emit
-from repro import obs, perf
+from repro import obs
 from repro.core.preferences import PAPER_PREFERENCES
 from repro.experiments import TrialSpec, run_trial, run_vectorized, serve
 
@@ -96,23 +98,36 @@ def _run_sequential(specs):
     return [run_trial(s) for s in specs]
 
 
+_TRAIN_SPANS = ("TRAIN",)
+_EVAL_SPANS = ("eval", "eval_stacked")
+
+
 def _timed_phases(fn):
-    """Run ``fn`` with fresh perf counters; returns (result, phase dict).
-    Per-phase call counts ride along (``perf.calls`` was tracked but never
-    exported before): for the vectorized engine they count packed cohort /
-    stacked eval dispatches, for sequential per-client / per-trial calls —
-    the amortization factor in one number."""
-    perf.reset()
+    """Run ``fn`` traced, from fresh span and metric buffers; returns
+    (result, seconds, phase dict).  ``train_s``/``eval_s`` are the self
+    time of the run's ``TRAIN`` and ``eval``/``eval_stacked`` spans; the
+    call counts are how many of each it opened — for the vectorized
+    engine packed cohort / stacked eval dispatches, for sequential
+    per-client / per-trial calls, the amortization factor in one number.
+    Tracing stays on afterwards if it was on before."""
+    was_on = obs.enabled()
+    obs.enable()
+    obs.registry.reset()
     t0 = time.perf_counter()
     res = fn()
     total = time.perf_counter() - t0
-    train = perf.seconds("train")
-    ev = perf.seconds("eval")
+    spans = list(obs.tracer.spans)
+    if not was_on:
+        obs.disable()
+    own = obs.self_durations([(sp.wall_t0, sp.wall_t1) for sp in spans])
+    train = sum(d for sp, d in zip(spans, own) if sp.name in _TRAIN_SPANS)
+    ev = sum(d for sp, d in zip(spans, own) if sp.name in _EVAL_SPANS)
     return res, total, {
         "total_s": round(total, 4), "train_s": round(train, 4),
         "eval_s": round(ev, 4),
         "other_s": round(max(total - train - ev, 0.0), 4),
-        "train_calls": perf.calls("train"), "eval_calls": perf.calls("eval")}
+        "train_calls": sum(sp.name in _TRAIN_SPANS for sp in spans),
+        "eval_calls": sum(sp.name in _EVAL_SPANS for sp in spans)}
 
 
 def main(settings=None, *, t: int = 8, rounds: int = 4, mode: str = "sync",
@@ -128,7 +143,7 @@ def main(settings=None, *, t: int = 8, rounds: int = 4, mode: str = "sync",
     seq, seq_s, seq_phases = _timed_phases(lambda: _run_sequential(specs))
 
     run_vectorized(specs, pack=pack)
-    # trace the timed vectorized run: occupancy and padding-waste land in
+    # the timed vectorized run's metrics (occupancy, padding waste) land in
     # BENCH.  Instrumentation is per-round host-side bookkeeping (gated,
     # parity-neutral), so vec_s stays an honest engine timing.
     obs.enable()

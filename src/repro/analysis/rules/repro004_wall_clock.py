@@ -5,8 +5,8 @@ legal stochasticity flows from seeded generators (``server rng`` /
 ``system_seed``).  ``time.*`` reads, ``datetime.now``, the global
 ``random`` module, unseeded ``np.random``, ``os.urandom`` and
 ``secrets`` all smuggle host nondeterminism into results — or worse,
-into event ordering.  Allowlisted by design: the ``obs/`` tracer and
-``perf`` shim (they *measure* wall time, that's their job) and the
+into event ordering.  Allowlisted by design: the ``obs/`` tracer (it
+*measures* wall time, that's its job) and the
 store's write-latency metric (``experiments/store.py``, explicitly
 carved out by the rule spec).  Wall-time measurements that feed purely
 informational fields (e.g. a RoundRecord's ``wall``) stay in scope and
@@ -25,7 +25,6 @@ ALLOWLIST_SUFFIXES = (
     "obs",                       # directory: the wall-clock tracer itself
 )
 ALLOWLIST_FILES = {
-    "perf.py",                   # wall-clock phase counters by contract
     "experiments/store.py",      # store_write_s latency metric
 }
 

@@ -12,7 +12,8 @@ Two checks keep the PR 6 observability layer honest as the engine grows:
 2. **Name catalog** — span names, metric names and phases are pinned in
    ``obs/trace_schema.json`` (``span_names`` / ``metric_names`` /
    ``phases``).  A literal name used at an ``obs.span``/``obs.record``/
-   ``obs.traced``/``obs.counter``/``registry.inc|sample|observe|gauge``
+   ``obs.traced``/``obs.begin``/``obs.step_span``/``obs.counter``/
+   ``registry.inc|sample|observe|gauge``
    call site that is missing from the catalog means ``tools/
    trace_report.py`` and downstream dashboards silently drop it.
 """
@@ -29,7 +30,7 @@ from ..scopes import FuncNode, dotted_parts, final_name
 COVERAGE_DIRS = {"runtime", "experiments"}
 STAGE_PREFIXES = ("plan_", "apply_", "account_", "finish_")
 REGISTRY_METHODS = {"inc", "sample", "observe", "gauge"}
-SPAN_CALLS = {"span", "record", "traced"}
+SPAN_CALLS = {"span", "record", "traced", "begin", "step_span"}
 
 _SCHEMA_PATH = Path(__file__).resolve().parents[2] / "obs" / \
     "trace_schema.json"

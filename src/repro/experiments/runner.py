@@ -78,7 +78,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs, perf
+from repro import obs
 from repro.configs.paper_models import MLPConfig
 from repro.core import CostModel, FedTune, FedTuneConfig, Preference
 from repro.core.tuner import FixedTuner, HyperParams
@@ -267,13 +267,13 @@ def _multi_cohort_fn(model, optimizer, prox_mu: float):
     one_client = make_client_step(model, optimizer, prox_mu)
 
     @jax.jit
-    def run(global_b, xs, ys, masks, active):
+    def cohort_step(global_b, xs, ys, masks, active):
         opt_b = jax.vmap(optimizer.init)(global_b)
         return cohort_scan(one_client, global_b, opt_b, xs, ys, masks,
                            active, global_b, global_in_axis=0)
 
-    _multi_cohort_cache[key] = run
-    return run
+    _multi_cohort_cache[key] = cohort_step
+    return cohort_step
 
 
 def _flatten_cohort(params_b):
@@ -316,7 +316,8 @@ def _sharded_multi_fn(model, optimizer, prox_mu: float, mesh, n_seg: int,
         return jax.lax.psum(partial, axis), last_loss
 
     @jax.jit
-    def run(global_b, xs, ys, masks, active, weights, seg, qref, enabled):
+    def cohort_step_sharded(global_b, xs, ys, masks, active, weights, seg,
+                            qref, enabled):
         in_specs = (jax.tree.map(lambda l: clients_spec(l.ndim, 0, axis),
                                  global_b),
                     clients_spec(xs.ndim, 1, axis),
@@ -336,8 +337,8 @@ def _sharded_multi_fn(model, optimizer, prox_mu: float, mesh, n_seg: int,
                                  global_b, xs, ys, masks, active, weights,
                                  seg, qref, enabled)
 
-    _sharded_multi_cache[key] = run
-    return run
+    _sharded_multi_cache[key] = cohort_step_sharded
+    return cohort_step_sharded
 
 
 @dataclass
@@ -741,9 +742,8 @@ def _sync_round_step(live: List[_LiveTrial], *, pack: str = "batched",
     groups: Dict[tuple, List[Tuple[_LiveTrial, int]]] = {}
     for ent in entries:
         groups.setdefault(_group_key(ent[0]), []).append(ent)
-    with perf.timed("train"), obs.span("TRAIN", phase="train",
-                                       n_entries=len(entries),
-                                       n_groups=len(groups)):
+    with obs.span("TRAIN", phase="train", n_entries=len(entries),
+                  n_groups=len(groups)):
         for ents in groups.values():
             fused = (pack == "sharded"
                      and all(tr.srv.aggregator.name == "fedavg"
@@ -1049,9 +1049,8 @@ class _EventEngine:
                 ln.params, ln.loss = ln.fl.params, 0.0
                 continue
             groups.setdefault(_group_key(ln.tr), []).append(ln)
-        with perf.timed("train"), obs.span("PACK", phase="train",
-                                           n_lanes=len(lanes),
-                                           n_groups=len(groups)):
+        with obs.span("TRAIN", phase="train", n_lanes=len(lanes),
+                      n_groups=len(groups)):
             for group in groups.values():
                 _run_event_group(group, min_lanes=min(4, len(live)))
         # 3. APPLY per trial, in collect (= merged pop) order: first fold

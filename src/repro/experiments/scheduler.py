@@ -40,8 +40,13 @@ independently.  A trial admitted mid-flight starts its virtual clock at 0
 exactly as a standalone run would; the pool's wall-clock interleaving is
 not part of any trial's result.
 
-Observability: ``admit``/``retire`` instant spans (wall clock, per-trial
-track), a ``pool_occupancy`` gauge sampled every scheduler step, plus
+Observability: a ``STEP`` span around each scheduler step (with profiler
+annotations on, also a ``serve_step`` step annotation that groups device
+work by step), an ``ADMIT`` span around each admission pass and a
+``RETIRE`` span around each retirement (store append and ``on_result``
+included), so a served step's host time falls under named spans.
+``admit``/``retire`` instant spans (wall clock, per-trial track), a
+``pool_occupancy`` gauge sampled every scheduler step, plus
 ``queue_depth`` and ``trials_admitted``/``trials_retired`` counters —
 ``tools/trace_report.py`` renders the drain from these.
 """
@@ -279,54 +284,57 @@ class TrialScheduler:
     def admit_pending(self) -> int:
         """Poll the watched submissions file, then admit queued trials
         into free lanes (queue order, lowest free lane first)."""
-        self.queue.poll()
-        n = 0
-        while self.queue and self.pool.n_free:
-            spec = self.queue.pop()
-            lane = self.pool.alloc(spec.key())
-            self.stats.admitted += 1
-            self.stats.admission_log.append((spec.key(), lane))
-            if obs.enabled():
-                obs.registry.inc("trials_admitted")
-                obs.record("admit", phase="admit", trial=spec.key(),
-                           lane=lane, step=self.stats.steps,
-                           queue_depth=len(self.queue))
-            if spec.mode == "sync":
-                self._sync_live.append(_make_live(spec))
-            else:
-                self._event_live.append(self._ev.admit(spec))
-            if self.verbose:
-                print(f"  serve: admit {spec.key()} -> lane {lane} "
-                      f"({self.pool.n_live}/{self.pool.capacity} live)",
-                      flush=True)
-            n += 1
-        return n
+        with obs.span("ADMIT", phase="admit", step=self.stats.steps):
+            self.queue.poll()
+            n = 0
+            while self.queue and self.pool.n_free:
+                spec = self.queue.pop()
+                lane = self.pool.alloc(spec.key())
+                self.stats.admitted += 1
+                self.stats.admission_log.append((spec.key(), lane))
+                if obs.enabled():
+                    obs.registry.inc("trials_admitted")
+                    obs.record("admit", phase="admit", trial=spec.key(),
+                               lane=lane, step=self.stats.steps,
+                               queue_depth=len(self.queue))
+                if spec.mode == "sync":
+                    self._sync_live.append(_make_live(spec))
+                else:
+                    self._event_live.append(self._ev.admit(spec))
+                if self.verbose:
+                    print(f"  serve: admit {spec.key()} -> lane {lane} "
+                          f"({self.pool.n_live}/{self.pool.capacity} live)",
+                          flush=True)
+                n += 1
+            return n
 
     # -- retirement -----------------------------------------------------
     def _retire(self, spec: TrialSpec, result: TrialResult):
-        lane = self.pool.release(spec.key())
-        self.queue.mark_done(spec.key())
-        self.stats.retired += 1
-        if obs.enabled():
-            obs.registry.inc("trials_retired")
-            obs.record("retire", phase="retire", trial=spec.key(),
-                       lane=lane, step=self.stats.steps,
-                       reached=result.reached, rounds=result.rounds)
-        if self.store is not None:
-            if self.store.is_completed(spec.key()):
-                # restored-and-replayed macro-step: this trial retired
-                # during the replayed step BEFORE the kill, so its row is
-                # already in the store — appending again would duplicate it
-                self.duplicates_suppressed += 1
-            else:
-                self.store.append(result.to_record())
-        self.results.append(result)
-        if self.on_result is not None:
-            self.on_result(result)
-        if self.verbose:
-            print(f"  serve: retire {spec.key()} <- lane {lane} "
-                  f"(reached={result.reached}, rounds={result.rounds})",
-                  flush=True)
+        with obs.span("RETIRE", phase="retire", trial=spec.key()):
+            lane = self.pool.release(spec.key())
+            self.queue.mark_done(spec.key())
+            self.stats.retired += 1
+            if obs.enabled():
+                obs.registry.inc("trials_retired")
+                obs.record("retire", phase="retire", trial=spec.key(),
+                           lane=lane, step=self.stats.steps,
+                           reached=result.reached, rounds=result.rounds)
+            if self.store is not None:
+                if self.store.is_completed(spec.key()):
+                    # restored-and-replayed macro-step: this trial
+                    # retired during the replayed step BEFORE the kill, so
+                    # its row is already in the store — appending again
+                    # would duplicate it
+                    self.duplicates_suppressed += 1
+                else:
+                    self.store.append(result.to_record())
+            self.results.append(result)
+            if self.on_result is not None:
+                self.on_result(result)
+            if self.verbose:
+                print(f"  serve: retire {spec.key()} <- lane {lane} "
+                      f"(reached={result.reached}, rounds={result.rounds})",
+                      flush=True)
 
     # -- the loop -------------------------------------------------------
     def step(self):
@@ -335,29 +343,31 @@ class TrialScheduler:
         whatever finished.  Freed lanes refill at the next
         ``admit_pending`` call."""
         self.stats.steps += 1
-        occ = self.pool.occupancy()
-        self.stats.occupancy_sum += occ
-        if obs.enabled():
-            obs.registry.sample("pool_occupancy", occ,
-                                step=self.stats.steps, engine="serve")
-            obs.registry.sample("queue_depth", len(self.queue),
-                                step=self.stats.steps)
-        if self._sync_live:
-            _sync_round_step(self._sync_live, pack=self._pack,
-                             mesh=self._mesh, step_idx=self._sync_steps)
-            self._sync_steps += 1
-            for tr in [t for t in self._sync_live if t.done]:
-                self._sync_live.remove(tr)
-                self._retire(tr.spec, _to_result(tr, self._sync_engine))
-        if self._event_live:
-            ended: List = []
-            self._ev.macro_step(self._event_live, ended.append)
-            for tr in ended:
-                self._event_live.remove(tr)
-                res = TrialResult.from_flresult(
-                    tr.spec, tr.eng.event_result(tr.st), tr.wall,
-                    self._event_engine)
-                self._retire(tr.spec, res)
+        with obs.step_span("STEP", self.stats.steps, annotation="serve_step",
+                           phase="step"):
+            occ = self.pool.occupancy()
+            self.stats.occupancy_sum += occ
+            if obs.enabled():
+                obs.registry.sample("pool_occupancy", occ,
+                                    step=self.stats.steps, engine="serve")
+                obs.registry.sample("queue_depth", len(self.queue),
+                                    step=self.stats.steps)
+            if self._sync_live:
+                _sync_round_step(self._sync_live, pack=self._pack,
+                                 mesh=self._mesh, step_idx=self._sync_steps)
+                self._sync_steps += 1
+                for tr in [t for t in self._sync_live if t.done]:
+                    self._sync_live.remove(tr)
+                    self._retire(tr.spec, _to_result(tr, self._sync_engine))
+            if self._event_live:
+                ended: List = []
+                self._ev.macro_step(self._event_live, ended.append)
+                for tr in ended:
+                    self._event_live.remove(tr)
+                    res = TrialResult.from_flresult(
+                        tr.spec, tr.eng.event_result(tr.st), tr.wall,
+                        self._event_engine)
+                    self._retire(tr.spec, res)
 
     # -- crash-safe snapshots -------------------------------------------
     def snapshot(self, path: Optional[str] = None) -> Optional[str]:

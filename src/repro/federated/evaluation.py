@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs, perf
+from repro import obs
 
 EVAL_BATCH = 256               # test batch staging granularity (bounds memory)
 
@@ -86,12 +86,12 @@ class EvalFnCache:
         if obs.enabled():
             obs.registry.inc("eval_fn_cache_misses")
 
-        def accuracy(params, x, y):
+        def eval_accuracy(params, x, y):
             logits = model.forward(params, x)
             return (logits.argmax(-1) == y).mean()
 
-        fn = (jax.jit(jax.vmap(accuracy, in_axes=(0, None, None)))
-              if stacked else jax.jit(accuracy))
+        fn = (jax.jit(jax.vmap(eval_accuracy, in_axes=(0, None, None)))
+              if stacked else jax.jit(eval_accuracy))
         while len(self._fns) >= self.capacity:
             self._fns.popitem(last=False)
         self._fns[key] = fn
@@ -162,7 +162,7 @@ class Evaluator:
         """Accuracy of ``params`` over the staged test batches."""
         fn = self.fn_cache.get(self.model)
         correct, total = 0.0, 0
-        with perf.timed("eval"), obs.span("eval", phase="eval", n_lanes=1):
+        with obs.span("eval", phase="eval", n_lanes=1):
             for bx, by, n in staged_batches(self.dataset, self.eval_points):
                 correct += float(fn(params, bx, by)) * n
                 total += n
@@ -221,8 +221,7 @@ class StackedEvaluator:
         fn = self.fn_cache.get(self.model, stacked=True)
         correct = [0.0] * t
         total = 0
-        with perf.timed("eval"), obs.span("eval_stacked", phase="eval",
-                                          n_lanes=t):
+        with obs.span("eval_stacked", phase="eval", n_lanes=t):
             for bx, by, n in staged_batches(self.dataset, self.eval_points):
                 accs = np.asarray(fn(stacked, bx, by))
                 for i in range(t):

@@ -23,6 +23,7 @@ from typing import Any, Callable, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.costs import CostModel, SystemCost
 from repro.core.tuner import HyperParams, Tuner
 from repro.data.synthetic import FederatedDataset
@@ -138,10 +139,9 @@ class FLServer:
         """Run one client's local training against ``params``.  Shared by the
         legacy loop and the event-driven runtime so both consume the server
         rng stream identically (batch permutations)."""
-        from repro import perf
         cfg = self.config
         x, y = self.dataset.client_data(int(cid))
-        with perf.timed("train"):
+        with obs.span("TRAIN", phase="train", n_lanes=1):
             upd = local_train(
                 self.model, params, x, y, passes=e,
                 batch_size=cfg.batch_size, optimizer=self.optimizer,

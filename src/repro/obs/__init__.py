@@ -9,15 +9,15 @@ measurable itself.  This package provides:
                 wall-clock), attributed to trial/lane/round/phase.
   ``metrics`` — a registry of counters/gauges/histograms/series (lane
                 occupancy, pack widths, pow2-padding waste, staleness,
-                dropout/straggler counts, cache hit rates) that also backs
-                the ``repro.perf`` phase-timer shim.
+                dropout/straggler counts, cache hit rates, collections).
   ``export``  — Chrome trace-event JSON (loadable in Perfetto: one track
                 per trial lane on both clocks), a metrics JSONL stream,
                 and the checked-in trace-schema validator.
 
 Contract: tracing is **zero-cost when disabled** (every instrumentation
 site either checks ``obs.enabled()`` or goes through ``obs.span``, which
-returns a shared no-op context manager when the tracer is off) and
+returns a shared no-op context manager when the tracer is off, and no
+``gc.callbacks`` hook is registered) and
 **bit-parity-neutral when enabled** — spans and metrics only read clocks
 and counts, never an rng or a float that feeds training.  Both halves are
 pinned in tests/test_obs.py.
@@ -36,7 +36,8 @@ from __future__ import annotations
 
 from repro.obs import metrics
 from repro.obs.metrics import registry
-from repro.obs.trace import NULL_SPAN, Span, Tracer, traced, tracer
+from repro.obs.trace import (NULL_SPAN, Span, Tracer, self_durations,
+                             traced, tracer)
 
 
 def enabled() -> bool:
@@ -46,13 +47,15 @@ def enabled() -> bool:
 
 
 def enable(jax_annotations: bool = False, reset: bool = True):
-    """Turn tracing + metric collection on.  ``jax_annotations=True``
+    """Turn tracing + metric collection on, with the GC hook (a ``GC``
+    span per generation-1/2 collection).  ``jax_annotations=True``
     additionally opens a ``jax.profiler.TraceAnnotation`` per span so a
     device profile taken alongside lines up with our spans."""
     tracer.enable(jax_annotations=jax_annotations, reset=reset)
 
 
 def disable():
+    """Turn tracing off and take the GC hook out of ``gc.callbacks``."""
     tracer.disable()
 
 
@@ -60,6 +63,12 @@ def span(name: str, **kw):
     """Context-managed span (see ``Tracer.span``); a shared no-op when
     tracing is disabled."""
     return tracer.span(name, **kw)
+
+
+def step_span(name: str, step_num: int, *, annotation: str, **kw):
+    """Span around one serving-loop iteration (see ``Tracer.step_span``);
+    a shared no-op when tracing is disabled."""
+    return tracer.step_span(name, step_num, annotation=annotation, **kw)
 
 
 def record(name: str, **kw):

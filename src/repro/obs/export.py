@@ -229,13 +229,12 @@ def metrics_rows(reg: Optional[MetricsRegistry] = None) -> List[Dict[str, Any]]:
         rows.append({"kind": "sample", **row})
     for name, value in sorted(reg.counters().items()):
         rows.append({"kind": "counter", "name": name, "value": value})
+    for row in reg.tagged_counters():
+        rows.append({"kind": "counter", **row})
     for name, value in sorted(reg.gauges().items()):
         rows.append({"kind": "gauge", "name": name, "value": value})
     for name, summary in reg.histograms().items():
         rows.append({"kind": "histogram", "name": name, **summary})
-    for name, secs in sorted(reg.phase_snapshot().items()):
-        rows.append({"kind": "phase", "name": name, "seconds": secs,
-                     "calls": reg.phase_call_count(name)})
     return rows
 
 

@@ -1,17 +1,15 @@
-"""Metrics registry: phase timers, counters, gauges, histograms, series.
+"""Metrics registry: counters, gauges, histograms, series.
 
-Two tiers with different always-on guarantees:
+Metrics (``inc``/``gauge``/``observe``/``sample``) are recorded
+unconditionally by this module but every call site gates on
+``obs.enabled()`` first (the tracer's GC hook exists only while the
+tracer is on), so with tracing off no metric call is even reached — that
+is the zero-cost contract, pinned in tests/test_obs.py.  Host time by
+phase is not kept here: it is the self time of the tracer's spans.
 
-* **Phase timers** (``phase``/``phase_add``/``phase_seconds``/
-  ``phase_call_count``) are always on — they are the backing store for
-  the ``repro.perf`` shim, whose ``timed("train")``/``timed("eval")``
-  split the benchmark suite has asserted on since PR 3.  Overhead is one
-  ``perf_counter`` pair and two dict updates per phase, same as the old
-  module-global implementation.
-* **Observability metrics** (``inc``/``gauge``/``observe``/``sample``)
-  are recorded unconditionally by this module but every call site gates
-  on ``obs.enabled()`` first, so with tracing off no metric call is even
-  reached — that is the zero-cost contract, pinned in tests/test_obs.py.
+A counter may carry tags (``inc("gc_s", dt, generation=0)``): each tag
+set is a counter of its own, exported as one row with the tags as
+fields.
 
 ``sample`` feeds the metrics JSONL stream (``obs.export``): a bounded
 list of ``{"name", "value", "step", ...tags}`` rows for time-series like
@@ -21,9 +19,7 @@ histograms (staleness, store write latency) summarized at export time.
 
 from __future__ import annotations
 
-import time
-from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 # Safety valve so a pathological run cannot grow the series list without
 # bound; 1M rows is far beyond any smoke/bench sweep (which emit ~1e3).
@@ -42,42 +38,19 @@ class MetricsRegistry:
     """Process-wide metric store (singleton at :data:`registry`)."""
 
     def __init__(self):
-        self._phase_s: Dict[str, float] = {}
-        self._phase_calls: Dict[str, int] = {}
         self._counters: Dict[str, float] = {}
+        self._tagged: Dict[Tuple[str, tuple], float] = {}
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, List[float]] = {}
         self._series: List[Dict[str, Any]] = []
 
-    # ---- phase timers (always on; repro.perf delegates here) ----------
+    # ---- recording (call sites gate on obs.enabled()) -----------------
 
-    def phase_add(self, name: str, seconds: float):
-        self._phase_s[name] = self._phase_s.get(name, 0.0) + seconds
-        self._phase_calls[name] = self._phase_calls.get(name, 0) + 1
-
-    @contextmanager
-    def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.phase_add(name, time.perf_counter() - t0)
-
-    def phase_seconds(self, name: str) -> float:
-        return self._phase_s.get(name, 0.0)
-
-    def phase_call_count(self, name: str) -> int:
-        return self._phase_calls.get(name, 0)
-
-    def phase_snapshot(self) -> Dict[str, float]:
-        return dict(self._phase_s)
-
-    def phase_calls_snapshot(self) -> Dict[str, int]:
-        return dict(self._phase_calls)
-
-    # ---- observability metrics (call sites gate on obs.enabled()) -----
-
-    def inc(self, name: str, value: float = 1.0):
+    def inc(self, name: str, value: float = 1.0, **tags):
+        if tags:
+            key = (name, tuple(sorted(tags.items())))
+            self._tagged[key] = self._tagged.get(key, 0.0) + value
+            return
         self._counters[name] = self._counters.get(name, 0.0) + value
 
     def gauge(self, name: str, value: float):
@@ -99,11 +72,21 @@ class MetricsRegistry:
 
     # ---- accessors ----------------------------------------------------
 
-    def counter_value(self, name: str) -> float:
+    def counter_value(self, name: str, **tags) -> float:
+        if tags:
+            return self._tagged.get((name, tuple(sorted(tags.items()))),
+                                    0.0)
         return self._counters.get(name, 0.0)
 
     def counters(self) -> Dict[str, float]:
+        """The untagged counters."""
         return dict(self._counters)
+
+    def tagged_counters(self) -> List[Dict[str, Any]]:
+        """The tagged counters, one ``{"name", "value", **tags}`` row per
+        tag set, sorted by name and tags."""
+        return [{"name": name, "value": value, **dict(tags)}
+                for (name, tags), value in sorted(self._tagged.items())]
 
     def gauges(self) -> Dict[str, float]:
         return dict(self._gauges)
@@ -133,18 +116,16 @@ class MetricsRegistry:
     def snapshot(self) -> Dict[str, Any]:
         """Everything at once — what the benchmark and exporters read."""
         return {
-            "phases": self.phase_snapshot(),
-            "phase_calls": self.phase_calls_snapshot(),
             "counters": self.counters(),
+            "tagged_counters": self.tagged_counters(),
             "gauges": self.gauges(),
             "histograms": self.histograms(),
             "n_series": len(self._series),
         }
 
     def reset(self):
-        self._phase_s.clear()
-        self._phase_calls.clear()
         self._counters.clear()
+        self._tagged.clear()
         self._gauges.clear()
         self._hists.clear()
         self._series.clear()

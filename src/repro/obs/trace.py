@@ -17,17 +17,29 @@ the tracer is off, and ``record``/``counter`` return immediately.  The
 tracer never touches rngs or training values, so enabling it cannot
 perturb results (bit-parity is pinned in tests/test_obs.py).
 
-This module imports nothing from the rest of ``repro`` so every layer —
-runtime, experiments, federated, launch — can instrument freely without
-import cycles.
+Garbage collection is traced too, while the tracer is on: ``enable``
+appends a hook to ``gc.callbacks`` and ``disable`` removes it, so with
+tracing off ``gc.callbacks`` is untouched.  Every generation-1 and
+generation-2 collection becomes a ``GC`` span (phase ``gc``) nested in
+whatever span was open when it struck; every collection, generation 0
+included, adds to the ``gc_collections`` and ``gc_s`` counters, tagged
+by generation.
+
+This module imports nothing from the rest of ``repro`` but the metrics
+registry (which imports nothing itself), so every layer — runtime,
+experiments, federated, launch — can instrument freely without import
+cycles.
 """
 
 from __future__ import annotations
 
 import functools
+import gc
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+from repro.obs.metrics import registry
 
 
 @dataclass
@@ -113,6 +125,56 @@ class _LiveSpan:
         return False
 
 
+class _StepAnnotation:
+    """A profiler step annotation with the span's own annotation inside
+    it: the step groups device work by step, the inner one carries the
+    span's name onto the profiler's host line."""
+
+    __slots__ = ("_outer", "_inner")
+
+    def __init__(self, outer, inner):
+        self._outer = outer
+        self._inner = inner
+
+    def __enter__(self):
+        self._outer.__enter__()
+        self._inner.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc_value, tb):
+        self._inner.__exit__(exc_type, exc_value, tb)
+        self._outer.__exit__(exc_type, exc_value, tb)
+        return False
+
+
+class _GCHook:
+    """The ``gc.callbacks`` entry of an enabled tracer: a ``GC`` span
+    around each generation-1/2 collection, counters for every one."""
+
+    __slots__ = ("_tracer", "_t0", "_span")
+
+    def __init__(self, tracer: "Tracer"):
+        self._tracer = tracer
+        self._t0 = 0.0
+        self._span = None
+
+    def __call__(self, phase: str, info: Dict[str, int]):
+        gen = info["generation"]
+        if phase == "start":
+            self._span = (self._tracer.begin("GC", phase="gc",
+                                             generation=gen)
+                          if gen else None)
+            self._t0 = time.perf_counter()
+            return
+        dt = time.perf_counter() - self._t0
+        if self._span is not None:
+            self._tracer.end(self._span, collected=info["collected"],
+                             uncollectable=info["uncollectable"])
+            self._span = None
+        registry.inc("gc_collections", generation=gen)
+        registry.inc("gc_s", dt, generation=gen)
+
+
 class Tracer:
     """Process-wide span collector (singleton at :data:`tracer`).
 
@@ -125,23 +187,32 @@ class Tracer:
         self.spans: List[Span] = []
         self.counters: List[Tuple[str, float, float]] = []
         self._annotation_cls: Optional[Callable] = None
+        self._step_annotation_cls: Optional[Callable] = None
+        self._gc_hook = _GCHook(self)
 
     def enable(self, jax_annotations: bool = False, reset: bool = True):
         if reset:
             self.clear()
         self._annotation_cls = None
+        self._step_annotation_cls = None
         if jax_annotations:
             try:
-                from jax.profiler import TraceAnnotation
+                from jax.profiler import StepTraceAnnotation, TraceAnnotation
                 self._annotation_cls = TraceAnnotation
+                self._step_annotation_cls = StepTraceAnnotation
             except (ImportError, AttributeError):
                 # profiler unavailable -> spans still work
-                self._annotation_cls = None
+                pass
+        if self._gc_hook not in gc.callbacks:
+            gc.callbacks.append(self._gc_hook)
         self.enabled = True
 
     def disable(self):
         self.enabled = False
         self._annotation_cls = None
+        self._step_annotation_cls = None
+        if self._gc_hook in gc.callbacks:
+            gc.callbacks.remove(self._gc_hook)
 
     def clear(self):
         self.spans = []
@@ -159,6 +230,35 @@ class Tracer:
         ann = (self._annotation_cls(name)
                if self._annotation_cls is not None else None)
         return _LiveSpan(self, sp, clock, ann)
+
+    def begin(self, name: str, *, phase: Optional[str] = None, **attrs):
+        """Open a span where no ``with`` block fits (a callback that sees
+        only the start and the end of what it times); close it with
+        ``end``.  Same clock and same profiler annotation as ``span``."""
+        return self.span(name, phase=phase, **attrs).__enter__()
+
+    def end(self, handle, **attrs):
+        """Close a span opened by ``begin``, adding ``attrs``."""
+        handle.set(**attrs).__exit__(None, None, None)
+
+    def step_span(self, name: str, step_num: int, *, annotation: str,
+                  phase: Optional[str] = None, **attrs):
+        """A span around one iteration of a serving loop (attr ``step``).
+        With profiler annotations on it also opens a
+        ``StepTraceAnnotation(annotation, step_num=step_num)`` around the
+        span's own annotation, so the profiler groups device work by
+        step."""
+        if not self.enabled:
+            return NULL_SPAN
+        sp = Span(name=name, phase=phase, attrs=dict(attrs, step=step_num))
+        ann = None
+        if self._annotation_cls is not None:
+            ann = self._annotation_cls(name)
+            if self._step_annotation_cls is not None:
+                ann = _StepAnnotation(
+                    self._step_annotation_cls(annotation,
+                                              step_num=step_num), ann)
+        return _LiveSpan(self, sp, None, ann)
 
     def record(self, name: str, *,
                wall: Optional[Tuple[float, float]] = None,
@@ -187,6 +287,26 @@ class Tracer:
 
 
 tracer = Tracer()
+
+
+def self_durations(intervals: Sequence[Tuple[float, float]]) -> List[float]:
+    """Each interval's length less the lengths of the intervals nested
+    directly inside it, in input order.  Spans of one thread nest
+    properly, so the direct children never overlap and the self times of
+    a tree add up to its root's length."""
+    order = sorted(range(len(intervals)),
+                   key=lambda i: (intervals[i][0], -intervals[i][1]))
+    out = [b - a for a, b in intervals]
+    stack: List[int] = []
+    for i in order:
+        a, b = intervals[i]
+        while stack and not (intervals[stack[-1]][0] <= a
+                             and b <= intervals[stack[-1]][1]):
+            stack.pop()
+        if stack:
+            out[stack[-1]] -= b - a
+        stack.append(i)
+    return out
 
 
 def traced(name: str, phase: Optional[str] = None):

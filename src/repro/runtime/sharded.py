@@ -116,7 +116,7 @@ def _make_sharded_cohort_fn(model: Model, optimizer: Optimizer,
         return jax.lax.psum(partial[0], axis), last_loss
 
     @jax.jit
-    def run(xs, ys, masks, active, weights, global_params):
+    def cohort_step_sharded(xs, ys, masks, active, weights, global_params):
         in_specs = (clients_spec(xs.ndim, 1, axis),
                     clients_spec(ys.ndim, 1, axis),
                     clients_spec(masks.ndim, 1, axis),
@@ -132,8 +132,8 @@ def _make_sharded_cohort_fn(model: Model, optimizer: Optimizer,
                                  xs, ys, masks, active, weights,
                                  global_params)
 
-    _sharded_fn_cache[key] = run
-    return run
+    _sharded_fn_cache[key] = cohort_step_sharded
+    return cohort_step_sharded
 
 
 def sharded_fedavg_train(model: Model, global_params,

@@ -1,25 +1,32 @@
 """Observability subsystem: zero-cost-when-disabled, bit-parity-neutral
-when enabled (sync + async sweeps), a schema-valid Perfetto trace with one
-track per trial lane on both clocks, the perf shim's back-compat surface,
-and the trace_report CLI round-trip."""
+when enabled (sync + async sweeps, served drains with the GC hook on), a
+schema-valid Perfetto trace with one track per trial lane on both clocks,
+GC/STEP/ADMIT/RETIRE spans on the profiler's clock, stable device program
+names, and the trace_report CLI round-trip."""
 
+import gc
 import importlib.util
 import json
 import os
+import re
 import time
 
 import numpy as np
 import pytest
 
-from repro import obs, perf
+import jax
+import jax.numpy as jnp
+
+from repro import obs
 from repro.experiments.grid import TrialSpec
 from repro.experiments.runner import run_vectorized
+from repro.experiments.scheduler import serve
 from repro.obs.export import (VIRTUAL_PID, WALL_PID, chrome_trace,
                               load_schema, read_metrics_jsonl,
                               trace_paths_for, validate_chrome_trace,
                               write_chrome_trace, write_metrics_jsonl)
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_SPAN, Tracer
+from repro.obs.trace import NULL_SPAN, Tracer, self_durations
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -59,7 +66,7 @@ def assert_bitexact(plain, traced):
 
 
 # ---------------------------------------------------------------------------
-# registry + perf shim
+# registry
 # ---------------------------------------------------------------------------
 
 def test_registry_counters_gauges_histograms_series():
@@ -83,32 +90,26 @@ def test_registry_counters_gauges_histograms_series():
     assert reg.counter_value("a") == 0.0 and reg.series() == []
 
 
-def test_perf_shim_back_compat():
-    """The pre-obs perf surface must keep working unchanged — the
-    benchmark suite and the federated layers call it every round."""
-    perf.reset()
-    with perf.timed("train"):
-        time.sleep(0.002)
-    perf.add("train", 1.0)
-    perf.add("eval", 0.25)
-    assert perf.seconds("train") > 1.0
-    assert perf.calls("train") == 2
-    assert perf.calls("missing") == 0 and perf.seconds("missing") == 0.0
-    snap = perf.snapshot()
-    assert set(snap) == {"train", "eval"} and snap["eval"] == 0.25
-    assert perf.calls_snapshot() == {"train": 2, "eval": 1}
-    perf.reset()
-    assert perf.snapshot() == {}
+def test_tagged_counters_keep_one_value_per_tag_set():
+    reg = MetricsRegistry()
+    reg.inc("gc_s", 0.5, generation=0)
+    reg.inc("gc_s", 0.25, generation=0)
+    reg.inc("gc_s", 2.0, generation=2)
+    assert reg.counter_value("gc_s", generation=0) == 0.75
+    assert reg.counter_value("gc_s", generation=2) == 2.0
+    assert reg.counter_value("gc_s") == 0.0 and reg.counters() == {}
+    assert reg.tagged_counters() == [
+        {"name": "gc_s", "value": 0.75, "generation": 0},
+        {"name": "gc_s", "value": 2.0, "generation": 2}]
+    reg.reset()
+    assert reg.tagged_counters() == []
 
 
-def test_perf_and_obs_share_one_registry():
-    with perf.timed("train"):
-        pass
-    assert obs.registry.phase_call_count("train") == 1
-    perf.reset()     # resets the WHOLE registry, metrics included
-    obs.registry.inc("x")
-    perf.reset()
-    assert obs.registry.counter_value("x") == 0.0
+def test_no_phase_timer_is_left():
+    """Host time by phase is span self time: the always-on phase timers
+    and the ``repro.perf`` module that fronted them are gone."""
+    assert importlib.util.find_spec("repro.perf") is None
+    assert not any(n.startswith("phase") for n in dir(MetricsRegistry))
 
 
 # ---------------------------------------------------------------------------
@@ -288,8 +289,7 @@ def test_metrics_jsonl_round_trip(tmp_path):
     obs.registry.inc("pack_steps_real", 30)
     obs.registry.inc("pack_steps_padded", 40)
     obs.registry.observe("staleness", 2)
-    with perf.timed("train"):
-        pass
+    obs.registry.inc("gc_s", 0.5, generation=0)
     obs.disable()
     path = str(tmp_path / "m.jsonl")
     n = write_metrics_jsonl(path)
@@ -304,8 +304,9 @@ def test_metrics_jsonl_round_trip(tmp_path):
     assert counters["pack_steps_real"] == 30.0
     (h,) = by_kind["histogram"]
     assert h["name"] == "staleness" and h["count"] == 1
-    (p,) = by_kind["phase"]
-    assert p["name"] == "train" and p["calls"] == 1
+    assert {"kind": "counter", "name": "gc_s", "value": 0.5,
+            "generation": 0} in by_kind["counter"]
+    assert "phase" not in by_kind
 
 
 def test_trace_paths_derive_from_the_store():
@@ -345,10 +346,19 @@ def test_trace_report_round_trips_a_traced_sweep(tmp_path, capsys):
         assert 0.0 < lane["occupancy"] <= 1.0
         assert lane["t_sim_s"] > 0
     assert rep["phases"]["train"]["calls"] > 0
+    # self time: nested spans are not counted twice, so the phases add up
+    # to no more than the traced stretch of wall time
+    ph = rep["phases"]
+    assert all(p["self_ms"] >= -1e-6 for p in ph.values())
+    assert ph["train"]["self_ms"] > 0 and ph["eval"]["self_ms"] > 0
+    wall = [ev for ev in json.load(open(trace_path))["traceEvents"]
+            if ev["ph"] == "X" and ev["pid"] == WALL_PID]
+    extent_ms = (max(ev["ts"] + ev["dur"] for ev in wall)
+                 - min(ev["ts"] for ev in wall)) / 1e3
+    assert sum(p["self_ms"] for p in ph.values()) <= extent_ms + 1e-6
     met = rep["metrics"]
     assert met["mean_lanes_live"] == pytest.approx(2.0)
     assert 0.0 <= met["padding_waste"] < 1.0
-    assert met["phase_calls"]["train"] > 0     # perf.calls surfaced
 
     assert tr.main([trace_path, "--metrics", metrics_path]) == 0
     out = capsys.readouterr().out
@@ -396,8 +406,252 @@ def test_event_sweep_emits_collect_pack_apply_and_inflight_spans():
     run_vectorized(specs)
     obs.disable()
     names = {sp.name for sp in obs.tracer.spans}
-    assert {"COLLECT", "PACK", "APPLY", "EVAL", "plan_event", "apply_event",
+    assert {"COLLECT", "TRAIN", "APPLY", "EVAL", "plan_event", "apply_event",
             "finish_event_round", "inflight", "agg_window"} <= names
+    # the event engine trains under TRAIN/train; PACK is the sync engine's
+    # data drawing alone
+    assert "PACK" not in names
+    assert {sp.phase for sp in obs.tracer.spans
+            if sp.name == "TRAIN"} == {"train"}
     infl = [sp for sp in obs.tracer.spans if sp.name == "inflight"]
     # in-flight windows are virtual-only: comp+trans long, zero wall width
     assert all(sp.virtual_dur > 0 and sp.wall_dur == 0.0 for sp in infl)
+
+
+# ---------------------------------------------------------------------------
+# GC spans, begin/end and step spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _inside(inner, outer):
+    return outer.wall_t0 <= inner.wall_t0 and inner.wall_t1 <= outer.wall_t1
+
+
+def test_forced_collection_inside_a_span_is_one_nested_gc_span():
+    before = list(gc.callbacks)
+    was_auto = gc.isenabled()
+    gc.disable()           # only the forced collection below may strike
+    try:
+        obs.enable()
+        with obs.span("outer"):
+            gc.collect()
+        obs.disable()
+    finally:
+        if was_auto:
+            gc.enable()
+    assert gc.callbacks == before
+    (outer,) = [sp for sp in obs.tracer.spans if sp.name == "outer"]
+    (g,) = [sp for sp in obs.tracer.spans if sp.name == "GC"]
+    assert g.phase == "gc" and _inside(g, outer) and g.wall_dur > 0
+    assert g.attrs["generation"] == 2
+    assert g.attrs["collected"] >= 0 and g.attrs["uncollectable"] >= 0
+    assert obs.registry.counter_value("gc_collections", generation=2) == 1
+    assert obs.registry.counter_value("gc_s", generation=2) > 0
+
+
+def test_gc_hook_is_registered_only_while_tracing():
+    before = list(gc.callbacks)
+    gc.collect()                         # tracing off: nothing recorded
+    assert gc.callbacks == before and obs.tracer.spans == []
+    obs.enable()
+    obs.enable(reset=False)              # a second enable adds no hook
+    assert len(gc.callbacks) == len(before) + 1
+    obs.disable()
+    assert gc.callbacks == before
+    obs.disable()                        # disabling twice is harmless
+    assert gc.callbacks == before
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records enter/exit
+    with the tracer's own clock reading between them."""
+    log = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name, self.kw))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name, self.kw))
+        return False
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_begin_end_spans_enter_and_exit_the_profiler_annotation(
+        fake_profiler):
+    obs.enable(jax_annotations=True)
+    h = obs.tracer.begin("GC", phase="gc", generation=1)
+    assert fake_profiler == [("enter", "GC", {})]
+    obs.tracer.end(h, collected=3, uncollectable=0)
+    assert fake_profiler == [("enter", "GC", {}), ("exit", "GC", {})]
+    was_auto = gc.isenabled()
+    gc.disable()
+    try:
+        gc.collect()                     # the hook goes the same way
+    finally:
+        if was_auto:
+            gc.enable()
+    obs.disable()
+    assert fake_profiler[2:] == [("enter", "GC", {}), ("exit", "GC", {})]
+    first, forced = [sp for sp in obs.tracer.spans if sp.name == "GC"]
+    assert first.attrs == {"generation": 1, "collected": 3,
+                           "uncollectable": 0}
+    assert first.wall_t0 <= first.wall_t1 <= forced.wall_t0
+    # off: begin hands back the shared no-op and end accepts it
+    assert obs.tracer.begin("GC", phase="gc") is NULL_SPAN
+    obs.tracer.end(NULL_SPAN, collected=0)
+
+
+def test_step_span_opens_a_step_annotation_around_its_own(fake_profiler):
+    assert obs.step_span("STEP", 7, annotation="serve_step") is NULL_SPAN
+    obs.enable(jax_annotations=True)
+    with obs.step_span("STEP", 7, annotation="serve_step", phase="step"):
+        pass
+    obs.disable()
+    assert fake_profiler == [("enter", "serve_step", {"step_num": 7}),
+                             ("enter", "STEP", {}), ("exit", "STEP", {}),
+                             ("exit", "serve_step", {"step_num": 7})]
+    (sp,) = obs.tracer.spans
+    assert (sp.name, sp.phase, sp.attrs) == ("STEP", "step", {"step": 7})
+
+
+def test_self_durations_subtract_direct_children_once():
+    spans = [(0.0, 10.0), (1.0, 4.0), (2.0, 3.0), (5.0, 5.0),
+             (6.0, 9.0), (20.0, 21.0)]
+    assert self_durations(spans) == pytest.approx(
+        [10.0 - 3.0 - 3.0, 3.0 - 1.0, 1.0, 0.0, 3.0, 1.0])
+    assert self_durations([]) == []
+
+
+# ---------------------------------------------------------------------------
+# served drains: STEP / ADMIT / RETIRE
+# ---------------------------------------------------------------------------
+
+SYNC_ENGINE_SPANS = {"PLAN", "PACK", "TRAIN", "APPLY", "EVAL", "REDUCE",
+                     "eval_stacked", "plan_sync_round", "account_sync_round"}
+
+
+def _served_specs():
+    return [tiny_spec(seed=s % 2, rounds=1 + s % 3,
+                      preference=((1.0, 0.0, 0.0, 0.0),
+                                  (0.25, 0.25, 0.25, 0.25))[s // 2])
+            for s in range(4)]
+
+
+def test_served_drain_emits_step_admit_and_retire_intervals():
+    specs = _served_specs()
+    obs.enable()
+    got = serve(specs, max_lanes=2)
+    obs.disable()
+    spans = obs.tracer.spans
+    steps = [sp for sp in spans if sp.name == "STEP"]
+    admits = [sp for sp in spans if sp.name == "ADMIT"]
+    retires = [sp for sp in spans if sp.name == "RETIRE"]
+    assert steps and admits and len(retires) == len(got) == len(specs)
+    assert {sp.phase for sp in steps} == {"step"}
+    assert [sp.attrs["step"] for sp in steps] == list(
+        range(1, len(steps) + 1))
+    assert {sp.phase for sp in admits} == {"admit"}
+    assert {sp.phase for sp in retires} == {"retire"}
+    assert all(sp.wall_dur > 0 for sp in steps + admits + retires)
+    # every admission happens under an ADMIT span, every retirement under
+    # a RETIRE span inside the STEP that finished the trial
+    for inst, outer in (("admit", admits), ("retire", retires)):
+        recs = [sp for sp in spans if sp.name == inst]
+        assert len(recs) == len(specs)
+        assert all(any(_inside(r, o) for o in outer) for r in recs)
+    assert all(any(_inside(r, s) for s in steps) for r in retires)
+    engine = [sp for sp in spans if sp.name in SYNC_ENGINE_SPANS]
+    assert {sp.name for sp in engine} >= {"PLAN", "PACK", "TRAIN", "APPLY",
+                                          "EVAL", "REDUCE"}
+    assert all(any(_inside(sp, s) for s in steps) for sp in engine)
+
+
+def test_traced_served_drain_is_bit_exact_with_the_gc_hook_on():
+    specs = _served_specs()
+
+    def collect(_res):
+        gc.collect()         # a generation-2 collection in every retirement
+
+    plain = serve(specs, max_lanes=2, on_result=collect)
+    before = list(gc.callbacks)
+    obs.enable(jax_annotations=True)
+    assert len(gc.callbacks) == len(before) + 1
+    traced = serve(specs, max_lanes=2, on_result=collect)
+    obs.disable()
+    assert gc.callbacks == before
+    key = lambda r: r.spec.key()      # noqa: E731
+    assert_bitexact(sorted(plain, key=key), sorted(traced, key=key))
+    retires = [sp for sp in obs.tracer.spans if sp.name == "RETIRE"]
+    gcs = [sp for sp in obs.tracer.spans
+           if sp.name == "GC" and sp.attrs["generation"] == 2]
+    assert len(gcs) >= len(specs)
+    assert all(any(_inside(g, r) for r in retires) for g in gcs[:len(specs)])
+
+
+# ---------------------------------------------------------------------------
+# stable device program names
+# ---------------------------------------------------------------------------
+
+def _tiny_model():
+    from repro.configs.paper_models import MLPConfig
+    from repro.models import build_model
+    from repro.optim.optimizers import get_optimizer
+    model = build_model(MLPConfig(name="mlp_names", in_dim=8, hidden=(4,),
+                                  n_classes=3))
+    return model, get_optimizer("sgd", 0.1, momentum=0.9)
+
+
+def _lower_program(which):
+    from jax.sharding import Mesh
+    from repro.experiments import runner
+    from repro.federated.evaluation import EvalFnCache
+    from repro.runtime import sharded
+    model, opt = _tiny_model()
+    params = model.init(jax.random.PRNGKey(0))
+    t, m, b, d = 2, 4, 5, 8
+    sds = jax.ShapeDtypeStruct
+    stacked = jax.tree.map(lambda p: sds((m,) + p.shape, p.dtype), params)
+    xs, ys = sds((t, m, b, d), jnp.float32), sds((t, m, b), jnp.int32)
+    masks, active = sds((t, m, b), jnp.float32), sds((t, m), jnp.bool_)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("clients",))
+    if which == "runner.cohort_step":
+        fn = runner._multi_cohort_fn(model, opt, 0.0)
+        return fn.lower(stacked, xs, ys, masks, active)
+    if which == "runner.cohort_step_sharded":
+        n = sum(p.size for p in jax.tree.leaves(params))
+        leaf_sizes = tuple(p.size for p in jax.tree.leaves(params))
+        fn = runner._sharded_multi_fn(model, opt, 0.0, mesh, 2, leaf_sizes)
+        return fn.lower(stacked, xs, ys, masks, active,
+                        sds((m,), jnp.float32), sds((m,), jnp.int32),
+                        sds((2, n), jnp.float32), sds((m,), jnp.bool_))
+    if which == "sharded.cohort_step_sharded":
+        fn = sharded._make_sharded_cohort_fn(model, opt, 0.0, mesh)
+        return fn.lower(xs, ys, masks, active, sds((m,), jnp.float32),
+                        params)
+    x, y = sds((b, d), jnp.float32), sds((b,), jnp.int32)
+    fn = EvalFnCache().get(model, stacked=which == "eval.stacked")
+    return fn.lower(stacked if which == "eval.stacked" else params, x, y)
+
+
+@pytest.mark.parametrize("which,module", [
+    ("runner.cohort_step", "jit_cohort_step"),
+    ("runner.cohort_step_sharded", "jit_cohort_step_sharded"),
+    ("sharded.cohort_step_sharded", "jit_cohort_step_sharded"),
+    ("eval.single", "jit_eval_accuracy"),
+    ("eval.stacked", "jit_eval_accuracy"),
+])
+def test_device_programs_lower_to_stable_module_names(which, module):
+    """The profiler's ``XLA Modules`` line names each device program by
+    its module; the per-step device metrics read these names."""
+    text = _lower_program(which).as_text()
+    assert re.search(r"module @(\S+)", text).group(1) == module

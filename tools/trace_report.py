@@ -6,7 +6,8 @@ optionally, the metrics JSONL written next to it) and prints:
 
   * schema validation (exit status 2 if the trace violates
     src/repro/obs/trace_schema.json),
-  * a wall-clock phase table (total ms + span counts per phase),
+  * a wall-clock phase table (span counts and self ms per phase: each
+    span's length less the spans nested in it, so no time counts twice),
   * a per-trial-lane virtual-time table: simulated span, busy time
     (round / agg_window spans), occupancy = busy / span,
   * a metrics summary (pack widths, padding waste, staleness, caches)
@@ -33,6 +34,7 @@ sys.path.insert(0, os.path.join(REPO, "src"))
 
 from repro.obs.export import (VIRTUAL_PID, VIRTUAL_US_PER_S, WALL_PID,
                               read_metrics_jsonl, validate_chrome_trace)
+from repro.obs.trace import self_durations
 
 # virtual spans whose union tiles a lane's busy time: sync rounds and
 # async/buffered aggregation windows (in-flight spans overlap; excluded)
@@ -52,7 +54,8 @@ def report(trace_path: str,
             track_names[(ev["pid"], ev["tid"])] = ev["args"]["name"]
 
     phases: Dict[str, Dict[str, float]] = defaultdict(
-        lambda: {"calls": 0, "wall_ms": 0.0})
+        lambda: {"calls": 0, "self_ms": 0.0})
+    wall: List[Dict[str, Any]] = []
     lanes: Dict[int, Dict[str, float]] = defaultdict(
         lambda: {"t0": float("inf"), "t1": 0.0, "busy": 0.0})
     # scheduler admit/retire instants: wall events sharing the trial's tid
@@ -63,10 +66,8 @@ def report(trace_path: str,
         # via the validator, and main() exits 2 on any violation
         if not isinstance(ev, dict) or ev.get("ph") != "X":
             continue
-        if ev.get("pid") == WALL_PID and "dur" in ev:
-            p = phases[ev.get("cat", "span")]
-            p["calls"] += 1
-            p["wall_ms"] += ev["dur"] / 1e3
+        if ev.get("pid") == WALL_PID and "dur" in ev and "ts" in ev:
+            wall.append(ev)
             if ev.get("name") in ("admit", "retire") and "tid" in ev:
                 args = ev.get("args") or {}
                 sched[ev["tid"]][f"{ev['name']}_ms"] = ev["ts"] / 1e3
@@ -79,6 +80,13 @@ def report(trace_path: str,
             lane["t1"] = max(lane["t1"], ev["ts"] + ev["dur"])
             if ev.get("name") in _BUSY_SPANS:
                 lane["busy"] += ev["dur"]
+
+    # host spans nest by time whatever track they sit on (one host thread)
+    own = self_durations([(ev["ts"], ev["ts"] + ev["dur"]) for ev in wall])
+    for ev, self_us in zip(wall, own):
+        p = phases[ev.get("cat", "span")]
+        p["calls"] += 1
+        p["self_ms"] += self_us / 1e3
 
     lane_rows: List[Dict[str, Any]] = []
     for tid in sorted(set(lanes) | set(sched)):
@@ -106,10 +114,12 @@ def report(trace_path: str,
 
     if metrics_path:
         rows = read_metrics_jsonl(metrics_path)
-        counters = {r["name"]: r["value"] for r in rows
-                    if r.get("kind") == "counter"}
+        counters = {r["name"]: r["value"] for r in rows    # untagged
+                    if r.get("kind") == "counter"
+                    and set(r) == {"kind", "name", "value"}}
+        gc_rows = [r for r in rows if r.get("kind") == "counter"
+                   and r["name"] in ("gc_collections", "gc_s")]
         hists = {r["name"]: r for r in rows if r.get("kind") == "histogram"}
-        ph_calls = {r["name"]: r for r in rows if r.get("kind") == "phase"}
         samples = defaultdict(list)
         for r in rows:
             if r.get("kind") == "sample":
@@ -118,8 +128,8 @@ def report(trace_path: str,
         out["metrics"] = {
             "counters": counters,
             "histograms": hists,
-            "phase_calls": {k: v.get("calls", 0)
-                            for k, v in ph_calls.items()},
+            "gc": {f"{r['name']}.gen{r.get('generation')}": r["value"]
+                   for r in gc_rows},
             "mean_lanes_live": (sum(samples["lanes_live"])
                                 / len(samples["lanes_live"])
                                 if samples["lanes_live"] else 0.0),
@@ -142,9 +152,9 @@ def _print_tables(rep: Dict[str, Any]):
     print(f"trace: {rep['trace']}  ({rep['n_events']} events, "
           f"{'valid' if rep['valid'] else 'INVALID'})")
     print("\nwall-clock phases")
-    print(f"  {'phase':<10} {'calls':>7} {'total ms':>10}")
+    print(f"  {'phase':<10} {'calls':>7} {'self ms':>10}")
     for name, p in rep["phases"].items():
-        print(f"  {name:<10} {int(p['calls']):>7} {p['wall_ms']:>10.2f}")
+        print(f"  {name:<10} {int(p['calls']):>7} {p['self_ms']:>10.2f}")
     if rep["lanes"]:
         served = any("admit_ms" in lane for lane in rep["lanes"])
         print("\nvirtual-clock lanes")
@@ -176,8 +186,8 @@ def _print_tables(rep: Dict[str, Any]):
             print(f"  pool occupancy  : {met['mean_pool_occupancy']:.1%}")
         if met.get("mean_queue_depth") is not None:
             print(f"  mean queue depth: {met['mean_queue_depth']:.2f}")
-        for name, calls in sorted(met["phase_calls"].items()):
-            print(f"  phase calls     : {name} x{calls}")
+        for name, value in sorted(met["gc"].items()):
+            print(f"  {name:<20}: {value:.4g}")
         for name in ("staleness", "store_write_s"):
             h = met["histograms"].get(name)
             if h and h.get("count"):
