@@ -21,16 +21,23 @@ PR 1-2 and runs all of them per virtual round:
               of MANY trials side by side — each vmap lane carries its own
               trial's global params (``global_in_axis=0``).
   3. REDUCE — aggregation.  Every FedAvg trial's weighted mean runs as ONE
-              fused ``fed_reduce`` dispatch per model group over the packed
-              flat cohort (segment ids = trial slots, raw example counts
-              normalized in-kernel, the int8 upload round trip of
-              compressed trials fused in against each trial's dispatch-time
-              globals) — bit-identical per lane to a standalone run because
-              the kernel folds each segment's rows left-to-right in pack
-              order (see kernels/ref.py).  Non-FedAvg trials hand their
-              per-client pytrees to their own aggregator, which itself
-              reduces through a T=1 ``fed_reduce``.  The ``sharded``
-              packing lays the flat cohort over the ``clients`` mesh axis
+              fused ``fed_reduce`` dispatch per model group over that
+              group's (M_pad, N) row matrix (segment ids = trial slots, raw
+              example counts normalized in-kernel, the int8 upload round
+              trip of compressed trials fused in against each trial's
+              dispatch-time globals).  Each FedAvg client's row is fixed
+              after PACK (trial after trial in live order, each trial's
+              clients in ``cids`` order), and each bucket's trained lanes
+              are written into the matrix by ONE placement program right
+              after its cohort step; zero-step clients (their trial's
+              globals) take one more placement.  No row is ever sliced
+              out or re-stacked on the host.  Bit-identical per lane to a
+              standalone run because the kernel folds each segment's rows
+              left-to-right in pack order (see kernels/ref.py).  Non-FedAvg
+              trials hand their per-client pytrees to their own
+              aggregator, which itself reduces through a T=1
+              ``fed_reduce``.  The ``sharded`` packing lays the flat
+              cohort over the ``clients`` mesh axis
               (runtime/sharded.py's mesh) and runs the same fused segment
               sum per device slice, completed by a psum — so per-client
               params never reach the host.
@@ -70,6 +77,7 @@ clients around a trial does not change its floats.
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -282,6 +290,19 @@ def _flatten_cohort(params_b):
     return jnp.concatenate([l.reshape(m, -1) for l in leaves], axis=1)
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _place_rows(rows, params_b, lane_of_row):
+    """Row r of the reduce matrix ``rows`` takes lane ``lane_of_row[r]`` of
+    the flattened cohort ``params_b``; rows marked -1 keep what they hold.
+    A gather and one select over the matrix, in place: a row scatter
+    rewrites the whole matrix once per row on the TPU, whose default
+    layout of an (M_pad, N) matrix puts the row index minor.  Compiled per
+    (rows, lanes) pow2 pair."""
+    flat = _flatten_cohort(params_b)
+    take = flat[jnp.maximum(lane_of_row, 0)]
+    return jnp.where((lane_of_row < 0)[:, None], rows, take)
+
+
 def _sharded_multi_fn(model, optimizer, prox_mu: float, mesh, n_seg: int,
                       leaf_sizes: tuple, compressed: bool = False):
     """Packed cohort over the ``clients`` mesh axis with per-trial FedAvg
@@ -348,9 +369,52 @@ class _Cohort:
     n_steps: List[int]
     sizes: List[int]
     trained: List[Any] = field(default_factory=list)   # per-client pytrees
-    flat_rows: List[Any] = field(default_factory=list)  # per-client (N,) rows
     losses: List[float] = field(default_factory=list)
     agg_params: Any = None    # set when aggregation was fused on device
+    reduce: Optional[_ReduceRows] = None  # FedAvg: the matrix of its rows
+    row0: int = 0             # the row of client 0 in ``reduce.rows``
+
+
+@dataclass(eq=False)
+class _ReduceRows:
+    """One model group's fused FedAvg reduce: the (M_pad, N) row matrix
+    that every client of its trials is placed into, trial after trial and
+    each trial's clients in ``cids`` order (the kernel's pack order).
+    Padding rows stay zero and get weight 0."""
+    trials: List[_LiveTrial]
+    rows: Any
+
+    def place(self, params_b, lane_of_row: np.ndarray):
+        """ONE placement dispatch: row r takes lane ``lane_of_row[r]`` of
+        ``params_b``; rows marked -1 are left as they are."""
+        self.rows = _place_rows(self.rows, params_b,
+                                jnp.asarray(lane_of_row))
+        if obs.enabled():
+            obs.registry.inc("reduce_row_places")
+            obs.registry.inc("reduce_rows_placed",
+                             int((lane_of_row >= 0).sum()))
+
+
+def _reduce_layout(trials: List[_LiveTrial]) -> List[_ReduceRows]:
+    """Fix, before training, the row every client of the given FedAvg
+    trials takes in its model group's reduce matrix, and allocate one
+    zero matrix per group (a group may span several TRAIN groups: those
+    are keyed finer than the model)."""
+    by_model: Dict[int, List[_LiveTrial]] = {}
+    for tr in trials:
+        by_model.setdefault(id(tr.srv.model), []).append(tr)
+    out = []
+    for grp in by_model.values():
+        rg = _ReduceRows(trials=grp, rows=None)
+        n_rows = 0
+        for tr in grp:
+            tr.cohort.reduce, tr.cohort.row0 = rg, n_rows
+            n_rows += len(tr.cohort.cids)
+        leaves = jax.tree.leaves(grp[0].params)
+        rg.rows = jnp.zeros((_pow2(n_rows), sum(l.size for l in leaves)),
+                            jnp.result_type(*leaves))
+        out.append(rg)
+    return out
 
 
 @dataclass(eq=False)     # identity semantics: trials are packed by object
@@ -392,9 +456,10 @@ _note_pack = note_pack_metrics      # pack-shape metrics, see batched.py
 
 def _run_group_batched(ents: List[Tuple[_LiveTrial, int]]):
     """Train one model-group's packed entries; results land back in each
-    trial's cohort.  FedAvg trials keep their clients as rows of the
-    bucket's flat (M, N) matrix (their aggregation runs as one fused
-    ``fed_reduce`` over those rows in ``_fused_sync_reduce``); other
+    trial's cohort.  FedAvg lanes of each bucket are written, in one
+    placement dispatch, straight into the rows ``_reduce_layout`` fixed
+    for them in their group's reduce matrix (their aggregation runs as
+    one fused ``fed_reduce`` over it in ``_fused_sync_reduce``); other
     aggregators get per-client pytree slices.  Each trial's global params
     enter the pack through ONE per-round stack + an on-device gather per
     bucket, so host-side tree work stays O(trials), not O(clients).
@@ -439,12 +504,18 @@ def _run_group_batched(ents: List[Tuple[_LiveTrial, int]]):
                          + [None] * (m_pad - len(sel)))
         if mask is not None:
             params_b = compress_delta_lanes(global_b, params_b, mask)
-        flat = _flatten_cohort(params_b)
+        # every FedAvg lane of a group shares one model, so one matrix
+        rg = next((tr.cohort.reduce for tr, _ in sel
+                   if tr.cohort.reduce is not None), None)
+        if rg is not None:
+            lane_of_row = np.full(rg.rows.shape[0], -1, np.int32)
+            for k, (tr, j) in enumerate(sel):
+                if tr.cohort.reduce is not None:
+                    lane_of_row[tr.cohort.row0 + j] = k
+            rg.place(params_b, lane_of_row)
         ll = np.asarray(last_loss)
         for k, (tr, j) in enumerate(sel):
-            if tr.srv.aggregator.name == "fedavg":
-                tr.cohort.flat_rows[j] = flat[k]
-            else:
+            if tr.cohort.reduce is None:
                 tr.cohort.trained[j] = jax.tree.map(
                     lambda p, k=k: p[k], params_b)
             tr.cohort.losses[j] = float(ll[k])
@@ -523,88 +594,63 @@ def _run_group_sharded(ents: List[Tuple[_LiveTrial, int]], mesh):
         tr.cohort.agg_params = _unflatten(agg[slot[id(tr)]], meta)
 
 
-def _fedavg_from_rows(tr: _LiveTrial) -> Any:
-    """FedAvg straight from the packed cohort's flat rows, as a T=1
-    ``fed_reduce`` (raw counts normalized in-kernel, the int8 round trip
-    fused when the trial compresses uploads) — the single-trial fallback
-    with the exact bits of one lane of ``_fused_sync_reduce``."""
-    from repro.kernels import ops as kernel_ops
-    co = tr.cohort
-    gflat, meta = _flatten(tr.params)
-    if tr._meta is None:
-        tr._meta = meta
-    rows = [r if r is not None else gflat
-            for r in co.flat_rows]     # zero-step clients stay at global
-    w = jnp.asarray(np.asarray(co.sizes, np.float32))
-    seg = jnp.zeros(len(rows), jnp.int32)
-    comp = tr.srv.config.compression not in (None, "none")
-    out = kernel_ops.fed_reduce(
-        w, jnp.stack(rows), seg, 1, normalize=True,
-        leaf_sizes=tuple(meta[2]) if comp else None,
-        quant_ref=gflat[None, :] if comp else None,
-        quant_enabled=jnp.ones(len(rows), bool) if comp else None)
-    return _unflatten(out[0], tr._meta)
-
-
-def _fused_sync_reduce(live: List[_LiveTrial]):
+def _fused_sync_reduce(groups: List[_ReduceRows]):
     """ONE ``fed_reduce`` dispatch per model group covering every FedAvg
-    trial's aggregation: each trial is a segment (lane) of the packed
-    (M, N) row matrix, raw example counts are normalized per segment
-    in-kernel, and compressed trials' int8 upload round trips run against
-    their own stacked global params inside the same dispatch.  Fills
-    ``cohort.agg_params``; ``_reduce_round`` consumes it.  Bit-identical
-    per trial to the standalone ``FedAvg.__call__`` path because the
-    kernel's per-segment fold only ever sees that trial's rows, in the
-    same client order (kernels/ref.py's packing-invariance contract)."""
+    trial's aggregation: each trial is a segment (lane) of the group's
+    placed (M_pad, N) row matrix, raw example counts are normalized per
+    segment in-kernel, and compressed trials' int8 upload round trips run
+    against their own stacked global params inside the same dispatch.
+    Zero-step clients' rows (their trial's globals) are placed first, in
+    one more placement.  Fills ``cohort.agg_params``; ``_reduce_round``
+    consumes it.  Bit-identical per trial to the standalone
+    ``FedAvg.__call__`` path because the kernel's per-segment fold only
+    ever sees that trial's rows, in the same client order
+    (kernels/ref.py's packing-invariance contract)."""
     from repro.kernels import ops as kernel_ops
-    todo = [tr for tr in live
-            if tr.cohort is not None and tr.cohort.cids
-            and tr.cohort.agg_params is None
-            and tr.srv.aggregator.name == "fedavg"]
-    groups: Dict[int, List[_LiveTrial]] = {}
-    for tr in todo:
-        groups.setdefault(id(tr.srv.model), []).append(tr)
-    for grp in groups.values():
-        t_pad = _pow2(len(grp))
-        rows, w, seg, en, qrefs = [], [], [], [], []
+    for grp in groups:
+        t_pad = _pow2(len(grp.trials))
+        m_pad = grp.rows.shape[0]
+        w, seg, en, qrefs = [], [], [], []
+        zero_slot = np.full(m_pad, -1, np.int32)    # row -> trial slot
         meta = None
-        for s, tr in enumerate(grp):
+        for s, tr in enumerate(grp.trials):
             co = tr.cohort
             gflat, meta = _flatten(tr.params)
             if tr._meta is None:
                 tr._meta = meta
             qrefs.append(gflat)
             comp = tr.srv.config.compression not in (None, "none")
-            for j in range(len(co.cids)):
-                r = co.flat_rows[j]
-                rows.append(r if r is not None else gflat)
+            for j, t in enumerate(co.n_steps):
                 w.append(co.sizes[j])
                 seg.append(s)
                 en.append(comp)
-        m_pad = _pow2(len(rows))
-        n = rows[0].shape[0]
-        rows += [jnp.zeros(n, rows[0].dtype)] * (m_pad - len(rows))
+                if t == 0:                # never trained: stays at global
+                    zero_slot[co.row0 + j] = s
         pad = m_pad - len(w)
         w += [0.0] * pad                  # zero-weight rows are bit-neutral
         seg += [0] * pad
         en += [False] * pad
         quant = any(en)
-        if quant:
-            qrefs += [jnp.zeros(n, qrefs[0].dtype)] * (t_pad - len(qrefs))
+        zero = bool((zero_slot >= 0).any())
+        if quant or zero:
+            qrefs += [jnp.zeros_like(qrefs[0])] * (t_pad - len(qrefs))
+            qref = jnp.stack(qrefs)       # (T_pad, N) dispatch-time globals
+        if zero:
+            grp.place(qref, zero_slot)
         if obs.enabled():
             obs.registry.inc("reduce_fused_dispatches")
             obs.registry.sample("reduce_rows", m_pad)
-            obs.registry.sample("reduce_lanes", len(grp))
-        with obs.span("REDUCE", phase="apply", n_lanes=len(grp),
+            obs.registry.sample("reduce_lanes", len(grp.trials))
+        with obs.span("REDUCE", phase="apply", n_lanes=len(grp.trials),
                       n_rows=m_pad):
             out = kernel_ops.fed_reduce(
-                jnp.asarray(np.asarray(w, np.float32)), jnp.stack(rows),
+                jnp.asarray(np.asarray(w, np.float32)), grp.rows,
                 jnp.asarray(np.asarray(seg, np.int32)), t_pad,
                 normalize=True,
                 leaf_sizes=tuple(meta[2]) if quant else None,
-                quant_ref=jnp.stack(qrefs) if quant else None,
+                quant_ref=qref if quant else None,
                 quant_enabled=jnp.asarray(np.asarray(en)) if quant else None)
-        for s, tr in enumerate(grp):
+        for s, tr in enumerate(grp.trials):
             tr.cohort.agg_params = _unflatten(out[s], tr._meta)
 
 
@@ -620,8 +666,6 @@ def _reduce_round(tr: _LiveTrial):
             srv.selector.update(int(cid), co.losses[j], co.sizes[j])
         if co.agg_params is not None:   # fused reduce (or sharded pack)
             tr.params = co.agg_params
-        elif srv.aggregator.name == "fedavg":
-            tr.params = _fedavg_from_rows(tr)
         else:
             updates = [
                 ClientUpdate(
@@ -735,20 +779,26 @@ def _sync_round_step(live: List[_LiveTrial], *, pack: str = "batched",
             tr.cohort = _Cohort(cids=cids, streams=streams,
                                 n_steps=n_steps, sizes=sizes,
                                 trained=[None] * len(cids),
-                                flat_rows=[None] * len(cids),
                                 losses=[0.0] * len(cids))
             entries.extend((tr, j) for j in range(len(cids)))
-    # 3. group by model and train each group's packed cohort
+    # 3. group by model and train each group's packed cohort; every
+    #    FedAvg client of the batched groups gets its reduce row first
     groups: Dict[tuple, List[Tuple[_LiveTrial, int]]] = {}
     for ent in entries:
         groups.setdefault(_group_key(ent[0]), []).append(ent)
+    sharded = set()    # trials of all-FedAvg groups, reduced on the mesh
+    if pack == "sharded":
+        for ents in groups.values():
+            if all(tr.srv.aggregator.name == "fedavg" for tr, _ in ents):
+                sharded.update(id(tr) for tr, _ in ents)
     with obs.span("TRAIN", phase="train", n_entries=len(entries),
                   n_groups=len(groups)):
+        reduce_groups = _reduce_layout(
+            [tr for tr in live if tr.cohort is not None
+             and tr.srv.aggregator.name == "fedavg"
+             and id(tr) not in sharded])
         for ents in groups.values():
-            fused = (pack == "sharded"
-                     and all(tr.srv.aggregator.name == "fedavg"
-                             for tr, _ in ents))
-            if fused:
+            if id(ents[0][0]) in sharded:
                 _run_group_sharded(ents, mesh)
             else:
                 _run_group_batched(ents)
@@ -756,7 +806,7 @@ def _sync_round_step(live: List[_LiveTrial], *, pack: str = "batched",
     #    every due trial (grouped by model/dataset), then per-trial
     #    record + controller step
     with obs.span("APPLY", phase="apply", n_trials=len(live)):
-        _fused_sync_reduce(live)       # one dispatch per model group
+        _fused_sync_reduce(reduce_groups)   # one dispatch per model group
         for tr in live:
             _reduce_round(tr)
     due = [tr for tr in live

@@ -283,6 +283,113 @@ def test_sharded_pack_matches_batched_pack():
 
 
 # ---------------------------------------------------------------------------
+# the FedAvg row hand-off: each bucket's trained lanes placed straight into
+# the group's reduce matrix (runner._reduce_layout / _place_rows)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def place_calls(monkeypatch):
+    """Every ``_place_rows`` dispatch as (matrix rows, lanes, zero-step:
+    lanes are trial globals), beside the jitted program itself."""
+    from types import SimpleNamespace
+    from repro.experiments import runner
+    spied = SimpleNamespace(calls=[], program=runner._place_rows)
+
+    def spy(rows, params_b, lane_of_row):
+        lanes = jax.tree.leaves(params_b)[0].shape[0]
+        spied.calls.append((rows.shape[0], lanes,
+                            isinstance(params_b, jax.Array)))
+        return spied.program(rows, params_b, lane_of_row)
+
+    monkeypatch.setattr(runner, "_place_rows", spy)
+    return spied
+
+
+@pytest.fixture
+def traced():
+    from repro import obs
+    obs.disable()
+    obs.tracer.clear()
+    obs.registry.reset()
+    obs.enable()
+    yield obs
+    obs.disable()
+    obs.tracer.clear()
+    obs.registry.reset()
+
+
+# E = 0.5 at M = 64 draws one-example clients (round(0.5) = 0 steps)
+ZERO_STEP = dict(seed=0, m0=64, e0=0.5)
+
+
+@pytest.mark.parametrize("specs", [
+    [tiny_spec(seed=1, compression="int8"),
+     tiny_spec(seed=2, aggregator="fednova"), tiny_spec(**ZERO_STEP)],
+    [tiny_spec(seed=1, batch_size=10), tiny_spec(**ZERO_STEP),
+     tiny_spec(seed=2, compression="int8", batch_size=10),
+     tiny_spec(seed=3, aggregator="fednova", batch_size=10)],
+], ids=["zero_step_int8_fednova_one_pack", "one_reduce_two_train_groups"])
+def test_fedavg_row_handoff_edge_cases_match_independent_runs(specs,
+                                                              place_calls):
+    """Zero-step FedAvg clients (placed from their trial's globals in one
+    more placement), an int8 FedAvg trial (round trip fused in the
+    reduce) and a FedNova trial (per-client pytrees) share buckets; in
+    the second case one model's reduce matrix also spans two TRAIN groups
+    (batch sizes 5 and 10).  Every trial stays bit-identical to a
+    standalone run."""
+    base = [run_trial(s) for s in specs]
+    vec = run_vectorized(specs)
+    for b, v in zip(base, vec):
+        assert_trial_parity(b, v)
+    assert any(zero for _, _, zero in place_calls.calls)
+
+
+@pytest.mark.parametrize("kw", [dict(m0=6), ZERO_STEP],
+                         ids=["trained_only", "with_zero_step"])
+def test_fedavg_rows_placed_once_per_bucket(kw, traced, monkeypatch):
+    """One traced round over FedAvg trials: one placement per bucket (every
+    bucket holds FedAvg lanes) plus one for zero-step rows, one placed row
+    per client, and no per-client row or pytree kept on the cohort."""
+    from dataclasses import fields
+    from repro.experiments import runner
+    seen = []
+    real = runner._fused_sync_reduce
+
+    def spy(groups):
+        seen.extend(tr.cohort for grp in groups for tr in grp.trials)
+        return real(groups)
+
+    monkeypatch.setattr(runner, "_fused_sync_reduce", spy)
+    live = [runner._make_live(tiny_spec(**{**kw, "seed": s}))
+            for s in range(3)]
+    n_entries = runner._sync_round_step(live)
+    reg = traced.registry
+    n_zero = n_entries - reg.counter_value("pack_lanes_real")
+    assert (n_zero > 0) == (kw is ZERO_STEP)
+    assert reg.counter_value("reduce_row_places") == (
+        reg.counter_value("pack_dispatches") + (n_zero > 0))
+    assert reg.counter_value("reduce_rows_placed") == n_entries
+    assert reg.counter_value("reduce_fused_dispatches") == 1
+    assert len(seen) == len(live)
+    assert "flat_rows" not in {f.name for f in fields(runner._Cohort)}
+    assert all(t is None for co in seen for t in co.trained)
+
+
+def test_row_placement_compiles_per_pow2_pair_only(place_calls):
+    """Over a sweep in which FedTune moves M, the placement program
+    compiles once per (matrix rows, bucket lanes) pow2 pair, never per
+    round."""
+    place_calls.program.clear_cache()
+    specs = [tiny_spec(seed=s, m0=5, rounds=6) for s in range(3)]
+    res = run_vectorized(specs)
+    assert any(len(set(r.history_m)) > 1 for r in res)
+    pairs = set(place_calls.calls)
+    assert all(n & (n - 1) == 0 for r, m, _ in pairs for n in (r, m))
+    assert (place_calls.program._cache_size() <= len(pairs)
+            < len(place_calls.calls))
+
+
+# ---------------------------------------------------------------------------
 # the stacked evaluation subsystem (federated/evaluation.py)
 # ---------------------------------------------------------------------------
 

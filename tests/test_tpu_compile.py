@@ -97,6 +97,26 @@ def test_fed_reduce_compiles(one_chip, served_model, m, t, int8):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("lanes", [128, 16],
+                         ids=["bucket_lanes", "zero_step_globals"])
+def test_row_placement_compiles_in_place(one_chip, served_model, lanes):
+    """The FedAvg row hand-off at the served width: a 128-lane bucket (or
+    16 trials' flat globals) placed into a 512-row reduce matrix, which is
+    donated, so the write aliases it on the chip."""
+    _, _, shapes = served_model
+    n = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cohort = (sds((lanes, n)) if lanes == 16 else
+              jax.tree.map(lambda s: sds((lanes,) + s.shape, s.dtype), shapes))
+    compiled = runner._place_rows.lower(
+        sds((512, n)), cohort, sds((512,), jnp.int32)).compile()
+    assert "input_output_alias" in compiled.as_text()
+    _fits(compiled)
+
+
 def test_fed_aggregate_async_mix_compiles(one_chip, served_model):
     """``fed_aggregate`` as FedAsync mixing calls it: one (1, N) row."""
     _, _, shapes = served_model
