@@ -3,8 +3,9 @@ traffic, measure a window, check what the window retired against the plain
 reference, and return the result line.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration file, ``traffic/<traffic>.json``, ``limits/<cell>.json``
-and ``metrics/<metric>.py`` for each per-layer metric that lists the cell.
+its configuration file, ``models/<kind>.py`` for the configuration's model
+(``kinds.py``), ``traffic/<traffic>.json``, ``limits/<cell>.json`` and
+``metrics/<metric>.py`` for each per-layer metric that lists the cell.
 """
 
 from __future__ import annotations
@@ -22,6 +23,8 @@ import time
 from pathlib import Path
 
 import numpy as np
+
+import kinds
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -92,23 +95,6 @@ class CompileCounter:
             self.compile_s += secs
 
 
-def place_model(config: dict) -> None:
-    """Serve the configuration's model. The served path builds one model per
-    (dataset, reduced) in ``runner._model_for``, always with one hidden
-    layer of 48 units; the configuration's widths are built by the
-    program's own ``build_model`` and placed where that function looks
-    first."""
-    from repro.configs.paper_models import MLPConfig
-    from repro.experiments import runner
-    from repro.models.registry import build_model
-    m = config["model"]
-    cfg = MLPConfig(name=m["name"], in_dim=m["in_dim"],
-                    hidden=tuple(m["hidden"]), n_classes=m["n_classes"])
-    key = (config["dataset"], config["reduced_dataset"])
-    if getattr(runner._model_cache.get(key), "config", None) != cfg:
-        runner._model_cache[key] = build_model(cfg)
-
-
 class Server:
     """The cell's traffic driven through the program's ``TrialScheduler``:
     a closed-loop backlog topped up with whole grids, one scheduler step
@@ -120,7 +106,7 @@ class Server:
         from repro.experiments.scheduler import TrialQueue, TrialScheduler
 
         from traffic import GridStream
-        place_model(config)
+        kinds.of(config["model"]).place(config)
         self.traffic = traffic
         self.stream = GridStream(traffic, config, seed)
         self.sched = TrialScheduler(TrialQueue(), max_lanes=traffic["lanes"],
@@ -188,18 +174,13 @@ class Server:
         return live + (done / c1 if c1 else 0.0)
 
 
-def _leaves(params) -> list:
-    return [np.asarray(x, np.float32) for layer in params["layers"]
-            for x in (layer["w"], layer["b"])]
-
-
-def _record(res, models) -> dict:
+def _record(res, models, leaves) -> dict:
     return {"history_m": list(res.history_m),
             "history_e": [float(e) for e in res.history_e],
             "history_acc": list(res.history_acc), "cost": list(res.cost),
             "rounds": res.rounds, "reached": res.reached,
             "final_m": res.final_m, "final_e": float(res.final_e),
-            "models": [_leaves(p) for p in models]}
+            "models": [leaves(p) for p in models]}
 
 
 def _finite(res) -> bool:
@@ -331,7 +312,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     log(f"window: {compiles} compiles inside the window")
     log(f"device: peak HBM {mem_peak} bytes on the fullest chip")
 
-    picked = [(server.submitted[k][1], _record(r, server.models[k]))
+    leaves = kinds.of(config["model"]).leaves
+    picked = [(server.submitted[k][1], _record(r, server.models[k], leaves))
               for k, _, r in sample(done, traffic["check_sample"], seed)]
     del server
     from reference import run_trial

@@ -7,7 +7,9 @@ the same clients; everything after that is written here, one client at a
 time: SGD with momentum on the masked mean cross-entropy, the
 example-weighted mean of the clients' models, accuracy on the pooled test
 set, eqs. (2)-(5) of the paper charged per round, and Algorithm 1 of the
-paper deciding (M, E).
+paper deciding (M, E). The model itself (initial weights, forward pass,
+parameter and FLOP counts) is the configuration's kind, ``models/<kind>.py``
+found through ``kinds.py``, which imports nothing of the program either.
 
 ``dtype=float32`` keeps the model in float32 with matrix products at the
 precision the configuration states (``precision.matmul``: ``default`` is
@@ -29,11 +31,14 @@ send the two runs down different (M, E) trajectories.
 
 from __future__ import annotations
 
+import json
 from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import kinds
 
 EPS = 1e-12
 M_SIGNS = (1.0, 1.0, -1.0, -1.0)   # CompT, TransT, CompL, TransL
@@ -109,42 +114,27 @@ def batches(x, y, batch_size: int, passes: float, rng):
     return out
 
 
-def init_params(model: dict, seed: int):
-    """He-normal weights and zero biases from the trial's seed."""
-    dims = [model["in_dim"], *model["hidden"], model["n_classes"]]
-    ks = jax.random.split(jax.random.PRNGKey(seed), len(dims) - 1)
-    out = []
-    for k, a, b in zip(ks, dims, dims[1:]):
-        out.append((jax.random.normal(k, (a, b)) * jnp.sqrt(2.0 / a))
-                   .astype(jnp.float32))
-        out.append(jnp.zeros((b,), jnp.float32))
-    return out
-
-
 def _precision(dtype, matmul: str):
     return (jax.lax.Precision.HIGHEST
             if dtype == jnp.float32 and matmul == "highest"
             else jax.lax.Precision.DEFAULT)
 
 
-def _logits(params, x, prec):
-    h = x
-    for i in range(0, len(params) - 2, 2):
-        h = jax.nn.relu(jnp.dot(h, params[i], precision=prec) + params[i + 1])
-    return jnp.dot(h, params[-2], precision=prec) + params[-1]
-
-
 @lru_cache(maxsize=None)
-def _local_sgd(dtype_name: str, matmul: str, lr: float, momentum: float):
+def _local_sgd(model_key: str, dtype_name: str, matmul: str, lr: float,
+               momentum: float):
     """One client's local training: SGD with momentum over its batches in
     order. Batches past the client's last are padding (``live`` false) and
     leave the state as it was, so step counts can be rounded up to a power
-    of two and the compiled shapes stay few."""
+    of two and the compiled shapes stay few. ``model_key`` is the
+    configuration's ``model`` entry (its kind and sizes) as JSON."""
+    model = json.loads(model_key)
+    logits = kinds.of(model).logits
     dtype = jnp.dtype(dtype_name)
     prec = _precision(dtype, matmul)
 
     def loss(params, x, y, mask):
-        logp = jax.nn.log_softmax(_logits(params, x, prec), axis=-1)
+        logp = jax.nn.log_softmax(logits(params, x, prec, model), axis=-1)
         nll = -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
         return (jnp.where(mask, nll, 0).sum()
                 / jnp.maximum(mask.sum(), 1).astype(dtype))
@@ -170,14 +160,16 @@ def _local_sgd(dtype_name: str, matmul: str, lr: float, momentum: float):
 
 
 @lru_cache(maxsize=None)
-def _correct(dtype_name: str, matmul: str):
+def _correct(model_key: str, dtype_name: str, matmul: str):
+    model = json.loads(model_key)
+    logits = kinds.of(model).logits
     dtype = jnp.dtype(dtype_name)
     prec = _precision(dtype, matmul)
 
     @jax.jit
     def count(params, x, y):
-        logits = _logits(params, x.astype(dtype), prec)
-        return jnp.sum(jnp.argmax(logits, -1) == y)
+        out = logits(params, x.astype(dtype), prec, model)
+        return jnp.sum(jnp.argmax(out, -1) == y)
 
     return count
 
@@ -274,14 +266,15 @@ class _Plain:
         xt, self.yt = self.fed.test_set(spec["eval_points"])
         self.xt = jnp.asarray(xt)
         matmul = config["precision"]["matmul"]
-        self.local = _local_sgd(dname, matmul, tr["lr"], tr["momentum"])
-        self.count = _correct(dname, matmul)
+        kind, mkey = kinds.of(model), json.dumps(model, sort_keys=True)
+        self.local = _local_sgd(mkey, dname, matmul, tr["lr"],
+                                tr["momentum"])
+        self.count = _correct(mkey, dname, matmul)
         self.params = [p.astype(dtype)
-                       for p in init_params(model, spec["seed"])]
+                       for p in kind.init_params(model, spec["seed"])]
         self.rng = np.random.default_rng(spec["seed"])
-        dims = [model["in_dim"], *model["hidden"], model["n_classes"]]
-        n_params = sum(a * b + b for a, b in zip(dims, dims[1:]))
-        fwd = float(sum(2 * a * b for a, b in zip(dims, dims[1:])))
+        n_params = kind.param_count(model)
+        fwd = kind.forward_flops(model)
         self.c1 = self.f(fwd) * self.f(config["costs"]["backward_multiplier"])
         self.down = self.up = self.f(n_params) * self.f(0.5)
         self.total = [self.f(0.0)] * 4

@@ -3,9 +3,9 @@ import pytest
 
 import work
 
-EMNIST = {"in_dim": 784, "hidden": [48], "n_classes": 62}
-EMNIST200 = {"in_dim": 784, "hidden": [200], "n_classes": 62}
-CIFAR = {"in_dim": 3072, "hidden": [48], "n_classes": 100}
+EMNIST = {"kind": "mlp", "in_dim": 784, "hidden": [48], "n_classes": 62}
+EMNIST200 = {"kind": "mlp", "in_dim": 784, "hidden": [200], "n_classes": 62}
+CIFAR = {"kind": "mlp", "in_dim": 3072, "hidden": [48], "n_classes": 100}
 
 
 @pytest.mark.parametrize("model, flops, params", [

@@ -13,6 +13,9 @@ federation is the data set, and how much a trial trains depends on it, so
 every run serves the same grids in the same order and the same amount of
 work; ``--seed`` orders the preference vectors within each grid (which
 trial takes which lane, and when). No two grids share a trial key.
+
+Fields of a spec that name the model come from the configuration: its
+``model["spec"]``, when there is one, is merged into every spec.
 """
 
 from __future__ import annotations
@@ -58,6 +61,7 @@ class GridStream:
             "batch_size": c["train"]["batch_size"], "lr": c["train"]["lr"],
             "eval_points": c["train"]["eval_points"],
             "target_accuracy": c["train"]["target_accuracy"],
+            **c["model"].get("spec", {}),
             "seed": fed_seed, **t["grid"], **alt,
         }
         order = self.rng.permutation(len(t["preferences"]))

@@ -3,8 +3,8 @@
 These are the benchmark's own counts: a change to the program cannot change
 how much work a step is said to hold.
 
-- ``train_flops_per_example``: forward and backward of one example through
-  the MLP, 6 FLOPs per weight (2 forward, 4 backward), biases left out.
+- ``param_count`` and ``train_flops_per_example``: the model's, counted by
+  its kind's module (``models/<kind>.py``), which states how.
 - ``fed_reduce_traffic``: what the fused FedAvg reduction has to move and
   compute for one call over (M, N) rows into T lanes, whatever implements
   it. Rows are read once; the (T, N) base is read and the (T, N) result
@@ -15,27 +15,19 @@ how much work a step is said to hold.
 
 from __future__ import annotations
 
+import kinds
+
 F32 = 4
 
 
-def mlp_dims(model: dict) -> list:
-    return [model["in_dim"], *model["hidden"], model["n_classes"]]
-
-
-def weight_count(model: dict) -> int:
-    """Weights of the MLP's matrices, biases left out."""
-    d = mlp_dims(model)
-    return sum(a * b for a, b in zip(d, d[1:]))
-
-
 def param_count(model: dict) -> int:
-    """Every parameter the server reduces: weights and biases."""
-    d = mlp_dims(model)
-    return sum(a * b + b for a, b in zip(d, d[1:]))
+    """Every parameter the server reduces."""
+    return kinds.of(model).param_count(model)
 
 
 def train_flops_per_example(model: dict) -> float:
-    return 6.0 * weight_count(model)
+    """Forward and backward of one example."""
+    return kinds.of(model).train_flops_per_example(model)
 
 
 def fed_reduce_traffic(m: int, n: int, t: int, *, quant: bool = False):
